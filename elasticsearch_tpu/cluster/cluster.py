@@ -1404,7 +1404,7 @@ class ClusterNode:
         (the pid is what distinguishes real worker processes), stepper
         errors, and this node's transport counters."""
         from ..index.filter_cache import FilterCache
-        from ..obs.device import HbmLedger
+        from ..obs.device import HbmLedger, accelerator_info
 
         with self.lock:
             engines = dict(self.engines)
@@ -1422,6 +1422,7 @@ class ClusterNode:
                 "pid": os.getpid(),
                 "inflight_searches": int(inflight),
             },
+            "accelerator": accelerator_info(),
             "indices": {
                 "docs": {"count": int(docs)},
                 "shards": {"count": len(engines)},

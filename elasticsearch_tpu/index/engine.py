@@ -143,7 +143,9 @@ class SegmentHandle:
                 return
             import jax
 
-            self.device.live = jax.device_put(self.live_host.copy())
+            self.device.live = jax.device_put(
+                self.live_host.copy(), self.device.live.sharding
+            )
             self.live_dirty = False
             self.live_epoch += 1
 
@@ -793,7 +795,7 @@ class Engine:
 
                     # staticcheck: ignore[lock-blocking-call] deliberate: the re-packed plane and its live mask must install atomically against concurrent refresh/delete; promotion is a rare background action, not a request path
                     handle.device.live = jax.device_put(
-                        handle.live_host.copy()
+                        handle.live_host.copy(), handle.device.live.sharding
                     )
                     handle.live_dirty = False
                     handle.live_epoch += 1
@@ -802,7 +804,7 @@ class Engine:
 
                     # staticcheck: ignore[lock-blocking-call] deliberate: same atomic plane+mask install as the dirty branch (epoch unchanged — the mask content equals what caches already keyed)
                     handle.device.live = jax.device_put(
-                        handle.live_host.copy()
+                        handle.live_host.copy(), handle.device.live.sharding
                     )
             self._demoted = False
             return True

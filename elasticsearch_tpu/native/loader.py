@@ -2,14 +2,18 @@
 
 The C++ sources live in native/ at the repo root; the shared library is
 compiled once with g++ (cached under native/build/) and loaded with
-ctypes. Everything using it falls back to pure Python when the toolchain
-or library is unavailable — the native layer is an accelerator, never a
-requirement.
+ctypes. The library's file name carries a hash of its source, so a build
+directory copied from elsewhere can never serve a library built from
+different source: a changed text_indexer.cpp names a library that does
+not exist yet, and it is built. Everything using it falls back to pure
+Python when the toolchain or library is unavailable — the native layer
+is an accelerator, never a requirement.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,31 +22,40 @@ _NATIVE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "native",
 )
-_LIB_PATH = os.path.join(_NATIVE_DIR, "build", "libestpu_native.so")
+_SRC = os.path.join(_NATIVE_DIR, "text_indexer.cpp")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
 
 
-def _build() -> bool:
-    src = os.path.join(_NATIVE_DIR, "text_indexer.cpp")
-    if not os.path.exists(src):
-        return False
-    if os.path.exists(_LIB_PATH) and os.path.getmtime(
-        _LIB_PATH
-    ) >= os.path.getmtime(src):
-        return True
+def _build() -> str | None:
+    """Path of the library built from the current source, building it if
+    no library of that source exists; None when it cannot be built."""
+    try:
+        with open(_SRC, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    lib_path = os.path.join(
+        _NATIVE_DIR, "build", f"libestpu_native-{digest}.so"
+    )
+    if os.path.exists(lib_path):
+        return lib_path
+    # Build under a private name, then rename: concurrent builders (test
+    # workers) never load a half-written library.
+    tmp = f"build/.libestpu_native-{digest}.{os.getpid()}.so"
     try:
         subprocess.run(
-            ["make", "-C", _NATIVE_DIR],
+            ["make", "-C", _NATIVE_DIR, f"LIB={tmp}"],
             check=True,
             capture_output=True,
             timeout=120,
         )
+        os.replace(os.path.join(_NATIVE_DIR, tmp), lib_path)
     except (OSError, subprocess.SubprocessError):
-        return False
-    return os.path.exists(_LIB_PATH)
+        return None
+    return lib_path
 
 
 def get_lib() -> ctypes.CDLL | None:
@@ -55,10 +68,11 @@ def get_lib() -> ctypes.CDLL | None:
         _tried = True
         if os.environ.get("ESTPU_DISABLE_NATIVE"):
             return None
-        if not _build():
+        lib_path = _build()
+        if lib_path is None:
             return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(lib_path)
         except OSError:
             return None
         i64, i32, u8 = (

@@ -4726,7 +4726,7 @@ class Node:
         }
 
     def nodes_info(self) -> dict:
-        import jax
+        from .obs.device import accelerator_info
 
         return {
             "cluster_name": self.cluster_name,
@@ -4735,10 +4735,7 @@ class Node:
                     "name": self.node_name,
                     "version": "8.0.0-tpu",
                     "roles": ["data", "ingest", "master"],
-                    "accelerator": {
-                        "platform": jax.devices()[0].platform,
-                        "device_count": jax.device_count(),
-                    },
+                    "accelerator": accelerator_info(),
                     "indexing_pressure": self.indexing_pressure.stats(),
                 }
             },

@@ -136,6 +136,20 @@ def _on_compile_event(key: str, duration_s: float, **_kw: Any) -> None:
         window.note_compile(duration_s)
 
 
+def accelerator_info() -> dict[str, Any]:
+    """The devices this process serves from, as JAX reports them — the
+    `accelerator` entry of `_nodes` and of every node's `_nodes/stats`
+    section (a ProcCluster worker reports its own, CPU unless told)."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
+    }
+
+
 def ensure_compile_listener() -> None:
     """Register the process-wide compile-event listener once. jax offers
     no unregister, so this is a lifetime hook — it only bumps counters."""

@@ -65,26 +65,14 @@ from .routing import shard_for_id
 
 
 def _shard_map(body, mesh: Mesh, in_specs, out_specs):
-    """`jax.shard_map` (public since 0.6, kw `check_vma`) or the older
-    `jax.experimental.shard_map.shard_map` (kw `check_rep`) — the mesh
-    serving path must work on both; replication checking is off either way
-    (the reduce mixes per-shard and replicated values)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    return _legacy_shard_map(
+    """`jax.shard_map` with replication checking off (the reduce mixes
+    per-shard and replicated values)."""
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
 
 

@@ -1033,6 +1033,9 @@ def main():
         "connections (defaults to ESTPU_TRANSPORT_KEY)",
     )
     args = parser.parse_args()
+    from ..utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     server, rest = create_server(
         args.host, args.port, args.data_path,
         replication_nodes=args.replication_nodes,

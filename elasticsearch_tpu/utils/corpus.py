@@ -22,6 +22,24 @@ def zipf_probs(vocab_size: int, alpha: float = 1.1) -> np.ndarray:
     return probs / probs.sum()
 
 
+def zipf_tokens(
+    n_docs: int,
+    vocab_size: int = 30_000,
+    seed: int = 13,
+    min_len: int = 8,
+    max_len: int = 60,
+) -> tuple[np.ndarray, np.ndarray]:
+    """(per-doc lengths, flat term ids) of the seeded Zipf corpus — the one
+    draw both build_zipf_segment and text-form generators (chip_smoke.py's
+    `_bulk` bodies, term `i` spelled `t<i>`) derive from."""
+    rng = np.random.default_rng(seed)
+    lengths = rng.integers(min_len, max_len, size=n_docs)
+    tokens = rng.choice(
+        vocab_size, size=int(lengths.sum()), p=zipf_probs(vocab_size)
+    ).astype(np.int64)
+    return lengths, tokens
+
+
 def build_zipf_segment(
     n_docs: int,
     vocab_size: int = 30_000,
@@ -38,11 +56,8 @@ def build_zipf_segment(
     CSR postings doc-ascending per term, SmallFloat norm bytes), built with
     vectorized numpy instead of the analysis chain.
     """
-    rng = np.random.default_rng(seed)
-    lengths = rng.integers(min_len, max_len, size=n_docs)
+    lengths, tokens = zipf_tokens(n_docs, vocab_size, seed, min_len, max_len)
     total = int(lengths.sum())
-    probs = zipf_probs(vocab_size)
-    tokens = rng.choice(vocab_size, size=total, p=probs).astype(np.int64)
     doc_of = np.repeat(np.arange(n_docs, dtype=np.int64), lengths)
 
     # (term, doc) -> tf via unique over a combined key; uniq is sorted by
@@ -127,3 +142,26 @@ def pick_query_terms(
         ]
         out.append(terms)
     return out
+
+
+def keyword_field(name: str, ordinals: np.ndarray, values: tuple[str, ...]) -> FieldIndex:
+    """A keyword FieldIndex where doc i holds `values[ordinals[i]]` — one
+    value per doc, built vectorized. `values` must be sorted (term ids are
+    lexicographic, as SegmentBuilder assigns them) and each must occur."""
+    n_docs = len(ordinals)
+    df = np.bincount(ordinals, minlength=len(values)).astype(np.int32)
+    if list(values) != sorted(values) or not df.all():
+        raise ValueError("keyword values must be sorted and each occur")
+    return FieldIndex(
+        name=name,
+        terms={v: i for i, v in enumerate(values)},
+        df=df,
+        offsets=np.concatenate([[0], np.cumsum(df)]).astype(np.int64),
+        doc_ids=np.argsort(ordinals, kind="stable").astype(np.int32),
+        tfs=np.ones(n_docs, dtype=np.float32),
+        norm_bytes=np.zeros(n_docs, dtype=np.uint8),
+        doc_count=n_docs,
+        sum_total_tf=n_docs,
+        has_norms=False,
+        present=np.ones(n_docs, dtype=bool),
+    )

@@ -60,6 +60,9 @@ def main() -> int:
     )
     args = parser.parse_args()
 
+    from elasticsearch_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     import jax
 
     from elasticsearch_tpu.node import Node

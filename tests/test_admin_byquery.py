@@ -7,6 +7,7 @@ update-settings action, cat APIs, and the reindex module
 
 import json
 
+import jax
 import pytest
 
 from elasticsearch_tpu.node import ApiError, Node
@@ -116,6 +117,9 @@ def test_index_info_and_cat_apis():
     assert r["indices"]["count"] >= 1
     status, r = rest.dispatch("GET", "/_nodes", {}, "")
     assert "node-0" in r["nodes"]
+    accelerator = r["nodes"]["node-0"]["accelerator"]
+    assert accelerator["platform"] == "cpu" and accelerator["device_count"] == 8
+    assert accelerator["device_kind"] == jax.devices()[0].device_kind
 
 
 @pytest.mark.parametrize("n_shards", [1, 3])

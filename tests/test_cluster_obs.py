@@ -43,6 +43,7 @@ MEMBER_SECTIONS = {
     "roles",
     "master",
     "process",
+    "accelerator",
     "indices",
     "search_resilience",
     "cluster_state",
@@ -384,6 +385,9 @@ class TestProcClusterObservability:
             assert section["process"]["pid"] != supervisor_pid
             assert section["roles"] == ["data", "master"]
             assert section["transport"]["kind"] == "tcp"
+            # Workers serve from the platform they were given: the CPU.
+            assert section["accelerator"]["platform"] == "cpu"
+            assert section["accelerator"]["device_kind"]
         tiebreaker = stats["nodes"]["tiebreaker"]
         assert tiebreaker["roles"] == ["master", "voting_only"]
         assert tiebreaker["indices"]["shards"]["count"] == 0

@@ -103,3 +103,27 @@ def test_tokenizer_matches_python_regex_on_ascii():
         ]
         assert got == [t.lower() for t in word_re.findall(text)]
     assert tokenize_ascii("naïve") is None  # non-ASCII refused
+
+
+def test_library_name_tracks_its_source(tmp_path, monkeypatch):
+    """A copied build directory never serves a library of other source:
+    the library's name carries its source's hash, so changed source names
+    a library that does not exist yet, and it is built."""
+    import hashlib
+    import os
+    import shutil
+
+    from elasticsearch_tpu.native import loader
+
+    for name in ("Makefile", "text_indexer.cpp"):
+        shutil.copy(os.path.join(loader._NATIVE_DIR, name), tmp_path / name)
+    src = tmp_path / "text_indexer.cpp"
+    monkeypatch.setattr(loader, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(loader, "_SRC", str(src))
+    first = loader._build()
+    digest = hashlib.sha256(src.read_bytes()).hexdigest()[:16]
+    assert first == str(tmp_path / "build" / f"libestpu_native-{digest}.so")
+    assert os.path.exists(first)
+    src.write_text(src.read_text() + "\n// changed\n")
+    second = loader._build()
+    assert second != first and os.path.exists(second)

@@ -35,6 +35,15 @@ def _routing(cluster, node_id, index="s", shard="0"):
     ]
 
 
+def test_workers_are_refused_the_chip_this_process_holds():
+    """One process per chip: once this process has imported JAX it holds
+    the devices, so workers may only be asked for the CPU."""
+    import jax  # noqa: F401
+
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        ProcCluster(2, jax_platforms="tpu")
+
+
 @pytest.fixture(scope="module")
 def procs():
     cluster = ProcCluster(
