@@ -236,29 +236,7 @@ class MicroBatcher:
             QUEUE_WAIT_MS_BUCKETS,
             "Queue wait before launch, milliseconds",
         )
-        # The batcher's leg of the per-launch timing story (ISSUE 14):
-        # riders' batch-queue waits and the coalesced launch's wall time
-        # land in the SAME estpu_launch_ms family as the kernel sites'
-        # dispatch/block splits, so one histogram answers "where does a
-        # batched search's time go" per phase.
-        from ..obs.metrics import LAUNCH_MS_BUCKETS
 
-        self._launch_queue_ms = metrics.histogram(
-            "estpu_launch_ms",
-            LAUNCH_MS_BUCKETS,
-            "Per-launch wall ms by plan class/backend and phase",
-            plan_class="batcher_group",
-            backend="batcher",
-            phase="queue",
-        )
-        self._launch_exec_ms = metrics.histogram(
-            "estpu_launch_ms",
-            LAUNCH_MS_BUCKETS,
-            "Per-launch wall ms by plan class/backend and phase",
-            plan_class="batcher_group",
-            backend="batcher",
-            phase="execute",
-        )
         def _queued_depth() -> int:
             # Scrapes race queue mutation: snapshot under the condition
             # lock (a lock-free sum can die mid-iteration and silently
@@ -389,7 +367,8 @@ class MicroBatcher:
             victim.event.set()
         if task is not None:
             task.add_cancel_listener(lambda: self._cancel_queued(item))
-        self._await(item)
+        with TRACER.annotate("batcher.await"):
+            self._await(item)
         if item.retry_solo:
             # Failure isolation: this rider failed inside the coalesced
             # launch — one individual retry on the plain per-request
@@ -759,20 +738,23 @@ class MicroBatcher:
                     it.task.span_name = "batcher.launch"
             launch_t0 = time.monotonic()
             try:
-                results = live[0].searcher.search_many(
-                    [it.request for it in live],
-                    tasks=[it.task for it in live],
-                )
+                # One launch serves every rider's trace: the profiler
+                # sees it once here, each rider's ring gets a
+                # `batcher.launch` span recorded below.
+                with TRACER.annotate("batcher.launch"):
+                    results = live[0].searcher.search_many(
+                        [it.request for it in live],
+                        tasks=[it.task for it in live],
+                    )
             # staticcheck: ignore[broad-except] whole-launch failure fans out to per-rider individual retries; each rider's own error (incl. cancellation) re-raises on its thread
             except Exception as e:  # whole-launch failure
                 results = [e] * len(live)
             launch_t1 = time.monotonic()
-            self._launch_exec_ms.observe((launch_t1 - launch_t0) * 1e3)
             if self.qos is not None:
                 # Windowed cost accounting: each rider's lane pays an
-                # equal share of the OBSERVED launch wall (the same
-                # wall estpu_launch_ms{phase="execute"} records) — the
-                # signal DRR deficits and shed-victim choice run on.
+                # equal share of the OBSERVED launch wall (the span
+                # `batcher.launch` times) — the signal DRR deficits and
+                # shed-victim choice run on.
                 share_ms = (launch_t1 - launch_t0) * 1e3 / max(1, len(live))
                 for it in live:
                     self.qos.charge(it.lane, share_ms)
@@ -859,7 +841,6 @@ class MicroBatcher:
                 self._wait_samples.append(item.queue_wait_s)
                 self._queue_wait_hist.observe(item.queue_wait_s * 1e3)
                 self._queue_wait_recent.record(item.queue_wait_s * 1e3)
-                self._launch_queue_ms.observe(item.queue_wait_s * 1e3)
                 if self.qos is not None:
                     # Per-lane windowed wait — the fairness arc's gate
                     # (and the lane's own Retry-After source).

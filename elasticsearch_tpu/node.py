@@ -3928,7 +3928,8 @@ class Node:
         cluster/hub-level registries, the process-wide analysis registry
         (estpu_analysis_calls_total), and every live cluster member's
         registry re-exposed with a `node=<id>` label per series —
-        counters additionally folded into `node="_cluster"` totals.
+        counters additionally folded into `node="_cluster"` totals — and
+        the process-wide span-time counters (obs/tracing.py).
         Federation happens only at scrape time (the same wire snapshot
         shape the procs `metrics_wire` action ships), never on the
         request hot path."""
@@ -3943,11 +3944,12 @@ class Node:
             return self._procs.metrics_text(
                 extra_snapshots=(
                     WireRegistrySnapshot(
-                        self.metrics.to_wire(), node=self.node_name
+                        self.metrics.to_wire(TRACER.metrics),
+                        node=self.node_name,
                     ),
                 )
             )
-        others: list = [ANALYSIS_METRICS]
+        others: list = [ANALYSIS_METRICS, TRACER.metrics]
         if self.replication is not None:
             gw_metrics = getattr(self.replication, "metrics", None)
             if gw_metrics is not None and gw_metrics is not self.metrics:

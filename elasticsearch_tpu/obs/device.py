@@ -29,10 +29,10 @@ Three pieces, all feeding `_nodes/stats` and `GET /_metrics`:
 
 - **ProfilerCapture** — `POST /_profiler/start` / `POST /_profiler/stop`
   drive `jax.profiler.start_trace`/`stop_trace` (single-flight, bounded
-  duration, 409 on double-start), return the Perfetto-loadable trace
-  directory, and stamp the capture window into the obs trace ring
-  (`profiler.capture` trace) so device traces and the PR-4/13 request
-  traces can be laid side by side on one clock.
+  duration, 409 on double-start) and return the Perfetto-loadable trace
+  directory. Every span the program opens is an `estpu/<span name>`
+  profiler annotation (obs/tracing.py), so the capture lays request work
+  and device ops on one clock.
 
 `LEDGER_LABELS` is the machine-checked label registry: staticcheck's
 registry-breaker-label rule fails the gate on any `CircuitBreaker.add`
@@ -456,10 +456,10 @@ class ProfilerCapture:
     `start()` opens `jax.profiler.start_trace(trace_dir)`; a watchdog
     timer force-stops the capture at `duration_s` (clamped to
     ESTPU_PROFILER_MAX_S, default 120) so a forgotten capture can never
-    grow a trace directory unbounded. `stop()` closes the capture,
-    returns the Perfetto trace directory, and stamps the capture window
-    into the obs trace ring as a `profiler.capture` trace whose span
-    covers [start, stop] on the same clock as every request trace."""
+    grow a trace directory unbounded. `stop()` closes the capture and
+    returns the Perfetto trace directory. The capture holds the program's
+    own spans as `estpu/<span name>` host events (obs/tracing.py), on
+    the same clock as the device's ops."""
 
     def __init__(self, base_dir: str | None = None):
         self._lock = threading.Lock()
@@ -502,8 +502,6 @@ class ProfilerCapture:
             self._captures += 1
             self._active = {
                 "trace_dir": trace_dir,
-                # staticcheck: ignore[wallclock-duration] user-facing capture start epoch timestamp; durations come from the monotonic twin
-                "started_at_ms": time.time() * 1e3,
                 "started_mono": time.monotonic(),
                 "bound_s": duration_s,
             }
@@ -537,29 +535,11 @@ class ProfilerCapture:
         if timer is not None:
             timer.cancel()
         duration_ms = (time.monotonic() - active["started_mono"]) * 1e3
-        # Stamp the capture window into the obs trace ring: one
-        # `profiler.capture` trace whose root span covers the window, so
-        # `GET /_traces` lays the device capture alongside request traces.
-        from .tracing import TRACER
-
-        handle = TRACER.start_trace(
-            "profiler.capture",
-            trace_dir=active["trace_dir"],
-            reason=reason,
-        )
-        if handle.span is not None:
-            handle.span.start_ms = active["started_at_ms"]
-            handle.span.start_mono = active["started_mono"]
-        with handle:
-            pass  # enter+exit: finish() seals the window into the ring
         return {
             "acknowledged": True,
             "trace_dir": active["trace_dir"],
             "duration_ms": round(duration_ms, 3),
             "stopped": reason,
-            "trace_id": (
-                handle.span.trace_id if handle.span is not None else None
-            ),
         }
 
     def status(self) -> dict[str, Any]:

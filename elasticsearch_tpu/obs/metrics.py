@@ -819,6 +819,12 @@ CATALOG = {
     "estpu_request_cache_entries": ("gauge", "indices.request_cache"),
     "estpu_faults_armed": ("gauge", "faults"),
     "estpu_traces_buffered": ("gauge", "obs"),
+    # Span-time counters (obs/tracing.py): per span name, spans closed,
+    # their wall seconds and self seconds, traced or not — the host time
+    # of each serving step between two scrapes.
+    "estpu_spans_total": ("counter", "obs"),
+    "estpu_span_seconds_total": ("counter", "obs"),
+    "estpu_span_self_seconds_total": ("counter", "obs"),
     "estpu_search_resilience_total": ("counter", "search_resilience"),
     "estpu_cluster_search_resilience_total": (
         "counter",
@@ -1390,8 +1396,11 @@ class _TimedLaunch:
         device finishes (execute split) and return the ready outputs."""
         import jax
 
+        from .tracing import TRACER
+
         self.t_disp = time.monotonic()
-        out = jax.block_until_ready(out)
+        with TRACER.span("device.wait"):
+            out = jax.block_until_ready(out)
         self.t_done = time.monotonic()
         return out
 

@@ -27,6 +27,23 @@ cross-host transport would.
 Finished traces land in a bounded ring buffer (ESTPU_TRACE_BUFFER, default
 256) served by `GET /_traces[/{trace_id}]`; ``?format=chrome`` renders
 Chrome trace-event JSON loadable in Perfetto / chrome://tracing.
+
+Every span a handle opens also reaches two more sinks, traced or not:
+
+- the JAX profiler: a ``jax.profiler.TraceAnnotation("estpu/<name>")``
+  around the span, so a capture (`POST /_profiler/start`, or any
+  ``jax.profiler`` trace of the process) holds the program's host work on
+  the same clock and thread lines as the device's ops. The micro-batcher's
+  scheduler thread has no trace context; its steps reach the profiler this
+  way, and ``Tracer.annotate`` names work that serves several traces at
+  once (a coalesced launch);
+- the span-time counters on `GET /_metrics`: per span name, how many
+  closed (`estpu_spans_total`), their wall seconds
+  (`estpu_span_seconds_total`) and their self seconds
+  (`estpu_span_self_seconds_total`: wall time minus that of the spans
+  nested inside on the same thread).
+
+Retrospective spans (``Tracer.record``) reach the ring only.
 """
 
 from __future__ import annotations
@@ -41,10 +58,34 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any
 
+from .metrics import MetricsRegistry
+
 # (trace_id, span_id) of the active span on this thread/context.
 _CURRENT: contextvars.ContextVar[tuple[str, str] | None] = (
     contextvars.ContextVar("estpu_trace_ctx", default=None)
 )
+
+# What every span's profiler annotation is named: PROFILER_PREFIX + name.
+PROFILER_PREFIX = "estpu/"
+_PROFILER_NAMES: dict[str, str] = {}
+_TRACE_ME = None  # jax.profiler.TraceAnnotation, imported on first use
+
+# Per thread, the stack of handles open on it (self-time accounting).
+_OPEN = threading.local()
+
+
+def _annotation(name: str):
+    """A profiler annotation for span `name`, not yet entered. No tags:
+    it is on the hot path, where an annotation costs ~1 us."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ME = TraceAnnotation
+    label = _PROFILER_NAMES.get(name)
+    if label is None:
+        label = _PROFILER_NAMES.setdefault(name, PROFILER_PREFIX + name)
+    return _TRACE_ME(label)
 
 
 def _new_trace_id() -> str:
@@ -156,18 +197,39 @@ class Span:
 class _SpanHandle:
     """Context manager for one span: activates it, finishes it, records
     errors without swallowing them, and (optionally) mirrors the span name
-    onto a Task so `GET /_tasks` can show what a task is doing now."""
+    onto a Task so `GET /_tasks` can show what a task is doing now.
 
-    __slots__ = ("tracer", "span", "_token", "_task", "_prev_task_span")
+    With no span (untraced, or `Tracer.annotate`) it still annotates the
+    profiler and feeds the span-time counters."""
 
-    def __init__(self, tracer: "Tracer", span: Span | None, task=None):
+    __slots__ = (
+        "tracer", "span", "name", "_token", "_task", "_prev_task_span",
+        "_annotation", "_t0", "_nested_s",
+    )
+
+    def __init__(
+        self,
+        tracer: "Tracer",
+        span: Span | None,
+        task=None,
+        name: str | None = None,
+    ):
         self.tracer = tracer
         self.span = span
+        self.name = span.name if span is not None else name
         self._token = None
         self._task = task
         self._prev_task_span = None
 
     def __enter__(self) -> Span | None:
+        self._annotation = _annotation(self.name)
+        self._annotation.__enter__()
+        try:
+            stack = _OPEN.stack
+        except AttributeError:
+            stack = _OPEN.stack = []
+        stack.append(self)
+        self._nested_s = 0.0
         if self.span is not None:
             self._token = _CURRENT.set(
                 (self.span.trace_id, self.span.span_id)
@@ -175,18 +237,31 @@ class _SpanHandle:
             if self._task is not None:
                 self._prev_task_span = getattr(self._task, "span_name", None)
                 self._task.span_name = self.span.name
+        self._t0 = time.perf_counter()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        if self.span is not None:
-            if exc is not None:
-                self.span.record_error(exc)
-            self.span.finish()
-            if self._token is not None:
-                _CURRENT.reset(self._token)
-            if self._task is not None:
-                self._task.span_name = self._prev_task_span
-            self.tracer._on_span_closed(self.span)
+        wall_s = time.perf_counter() - self._t0
+        stack = _OPEN.stack
+        if stack[-1] is self:
+            stack.pop()
+        else:
+            stack.remove(self)
+        if stack:
+            stack[-1]._nested_s += wall_s
+        try:
+            self.tracer._note_span(self.name, wall_s, wall_s - self._nested_s)
+            if self.span is not None:
+                if exc is not None:
+                    self.span.record_error(exc)
+                self.span.finish()
+                if self._token is not None:
+                    _CURRENT.reset(self._token)
+                if self._task is not None:
+                    self._task.span_name = self._prev_task_span
+                self.tracer._on_span_closed(self.span)
+        finally:
+            self._annotation.__exit__(None, None, None)
         return False  # never swallow
 
 
@@ -211,6 +286,10 @@ class Tracer:
             maxlen=self.max_traces
         )
         self._index: dict[str, list[Span]] = {}
+        # Span-time counters (module docstring), merged into the node's
+        # `GET /_metrics`. span name -> (count, wall s, self s) counters.
+        self.metrics = MetricsRegistry()
+        self._span_series: dict[str, tuple] = {}
 
     # --------------------------------------------------------- span entry
 
@@ -261,9 +340,16 @@ class Tracer:
         ctx = _CURRENT.get()
         if ctx is None:
             if not root:
-                return _SpanHandle(self, None)
+                return _SpanHandle(self, None, name=name)
             return self.start_trace(name, task=task, **tags)
         return self.span_from(ctx, name, task=task, **tags)
+
+    def annotate(self, name: str) -> _SpanHandle:
+        """A span for the profiler and the span-time counters only, never
+        the ring: for work that serves several traces at once, such as a
+        coalesced launch, whose per-rider ring spans `record()` writes
+        after the fact."""
+        return _SpanHandle(self, None, name=name)
 
     def span_from(
         self, ctx: tuple[str, str], name: str, task=None, **tags: Any
@@ -348,6 +434,34 @@ class Tracer:
             span.tags.update(tags)
 
     # ------------------------------------------------------------- sealing
+
+    def _note_span(self, name: str, wall_s: float, self_s: float) -> None:
+        series = self._span_series.get(name)
+        if series is None:
+            series = self._span_series.setdefault(
+                name,
+                (
+                    self.metrics.counter(
+                        "estpu_spans_total", "Spans closed, by name",
+                        span=name,
+                    ),
+                    self.metrics.counter(
+                        "estpu_span_seconds_total",
+                        "Wall seconds of closed spans, by name",
+                        span=name,
+                    ),
+                    self.metrics.counter(
+                        "estpu_span_self_seconds_total",
+                        "Wall seconds of closed spans minus the spans "
+                        "nested in them on the same thread, by name",
+                        span=name,
+                    ),
+                ),
+            )
+        count, wall, own = series
+        count.inc()
+        wall.inc(wall_s)
+        own.inc(max(0.0, self_s))
 
     def _on_span_closed(self, span: Span) -> None:
         # Lock-free fast path: only the trace's ROOT span seals anything

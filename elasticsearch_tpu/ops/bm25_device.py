@@ -771,6 +771,14 @@ def _execute_inner(seg, spec, arrays, k: int, bounds=None):
 # README "Conjunction execution".
 SPARSE_TPAD_MAX = 32
 
+# `jax.named_scope` names of the sparse kernel's phases. A profiler trace
+# carries them in each device op's name metadata, so device time splits
+# by phase: scoring (worklist gather, doc sort, run fold, filters), the
+# top-k over candidates, and the total-hits count.
+SCOPE_SCORE = "bm25.score"
+SCOPE_TOPK = "bm25.topk"
+SCOPE_TOTAL = "bm25.total"
+
 
 def supports_sparse(spec) -> bool:
     """Sparse execution covers precomputed-impact term disjunctions with a
@@ -840,36 +848,30 @@ def _sparse_bool_inner(seg, spec, arrays, k: int, bounds=None):
     children = arrays["children"]
     live = seg["live"]
     num_docs = live.shape[0]
-    (
-        docs_s,
-        run_sum,
-        eligible,
-        p,
-        kk,
-    ) = _sparse_candidates(seg, must_s[0], children[0], k, bounds=bounds)
-    sentinel = jnp.int32(num_docs)
-    safe_docs = jnp.minimum(docs_s, sentinel - 1)
+    with jax.named_scope(SCOPE_SCORE):
+        (
+            docs_s,
+            run_sum,
+            eligible,
+            p,
+            kk,
+        ) = _sparse_candidates(seg, must_s[0], children[0], k, bounds=bounds)
+        sentinel = jnp.int32(num_docs)
+        safe_docs = jnp.minimum(docs_s, sentinel - 1)
 
-    for idx_child, child_spec in enumerate(filter_s):
-        eligible &= _const_membership(
-            seg, child_spec, children[1 + idx_child], safe_docs, num_docs
-        )
-    base = 1 + len(filter_s)
-    for idx_child, child_spec in enumerate(must_not_s):
-        eligible &= ~_const_membership(
-            seg, child_spec, children[base + idx_child], safe_docs, num_docs
-        )
-    scores = run_sum * arrays["boost"]
-    key = jnp.where(eligible, scores, jnp.float32(NEG_INF))
-    kp = min(kk, p)
-    top_scores, top_pos = jax.lax.top_k(key, kp)
-    top_ids = docs_s[top_pos]
-    # staticcheck: ignore[traced-branch] kp and kk are Python ints derived from the static spec's worklist shape, not traced values; the branch is resolved at trace time
-    if kp < kk:
-        top_scores = jnp.pad(top_scores, (0, kk - kp), constant_values=NEG_INF)
-        top_ids = jnp.pad(top_ids, (0, kk - kp), constant_values=0)
-    total = jnp.sum(eligible, dtype=jnp.int32)
-    return top_scores, top_ids.astype(jnp.int32), total
+        for idx_child, child_spec in enumerate(filter_s):
+            eligible &= _const_membership(
+                seg, child_spec, children[1 + idx_child], safe_docs, num_docs
+            )
+        base = 1 + len(filter_s)
+        for idx_child, child_spec in enumerate(must_not_s):
+            eligible &= ~_const_membership(
+                seg, child_spec, children[base + idx_child], safe_docs,
+                num_docs,
+            )
+        scores = run_sum * arrays["boost"]
+        key = jnp.where(eligible, scores, jnp.float32(NEG_INF))
+    return _sparse_top_k(key, docs_s, eligible, kk, min(kk, p))
 
 
 def _sparse_lead_inner(seg, spec, arrays, k: int, bounds=None):
@@ -890,60 +892,54 @@ def _sparse_lead_inner(seg, spec, arrays, k: int, bounds=None):
     children = arrays["children"]
     live = seg["live"]
     num_docs = live.shape[0]
-    sentinel = jnp.int32(num_docs)
-    lead_spec = filter_s[lead]
-    docs, _vals, valid, _norm = _gather_tiles(
-        lead_spec, children[1 + lead], seg
-    )
-    cand = jnp.where(valid, docs, sentinel).reshape(-1)  # [P], doc-ascending
-    p = cand.shape[0]
-    safe = jnp.minimum(cand, sentinel - 1)
-    in_range = cand != sentinel
-    must_spec = must_s[0]
-    marr = children[0]
-    t_pad = must_spec[3]
-    field_planes = seg["fields"][must_spec[1]]
-    flat_docs = field_planes[0].reshape(-1)
-    flat_tn = field_planes[1].reshape(-1)
-    one = jnp.float32(1.0)
-    score = jnp.zeros(p, dtype=jnp.float32)
-    matched_any = jnp.zeros(p, dtype=bool)
-    for j in range(t_pad):
-        pos, found = _span_locate(
-            flat_docs, marr["term_starts"][j], marr["term_ends"][j], safe
+    with jax.named_scope(SCOPE_SCORE):
+        sentinel = jnp.int32(num_docs)
+        lead_spec = filter_s[lead]
+        docs, _vals, valid, _norm = _gather_tiles(
+            lead_spec, children[1 + lead], seg
         )
-        found &= in_range
-        w = marr["term_weights"][j]
-        contrib = w - w / (one + flat_tn[pos])
-        score = score + jnp.where(found, contrib, jnp.float32(0.0))
-        matched_any |= found
-    eligible = matched_any & in_range & live[safe]
-    if bounds is not None:
-        eligible &= (cand >= bounds[0]) & (cand < bounds[1])
-    for idx_child, child_spec in enumerate(filter_s):
-        if idx_child == lead:
-            continue
-        eligible &= _const_membership(
-            seg, child_spec, children[1 + idx_child], safe, num_docs
-        )
-    base = 1 + len(filter_s)
-    for idx_child, child_spec in enumerate(must_not_s):
-        eligible &= ~_const_membership(
-            seg, child_spec, children[base + idx_child], safe, num_docs
-        )
-    scores = score * arrays["boost"]
-    key = jnp.where(eligible, scores, jnp.float32(NEG_INF))
+        # [P], doc-ascending
+        cand = jnp.where(valid, docs, sentinel).reshape(-1)
+        p = cand.shape[0]
+        safe = jnp.minimum(cand, sentinel - 1)
+        in_range = cand != sentinel
+        must_spec = must_s[0]
+        marr = children[0]
+        t_pad = must_spec[3]
+        field_planes = seg["fields"][must_spec[1]]
+        flat_docs = field_planes[0].reshape(-1)
+        flat_tn = field_planes[1].reshape(-1)
+        one = jnp.float32(1.0)
+        score = jnp.zeros(p, dtype=jnp.float32)
+        matched_any = jnp.zeros(p, dtype=bool)
+        for j in range(t_pad):
+            pos, found = _span_locate(
+                flat_docs, marr["term_starts"][j], marr["term_ends"][j], safe
+            )
+            found &= in_range
+            w = marr["term_weights"][j]
+            contrib = w - w / (one + flat_tn[pos])
+            score = score + jnp.where(found, contrib, jnp.float32(0.0))
+            matched_any |= found
+        eligible = matched_any & in_range & live[safe]
+        if bounds is not None:
+            eligible &= (cand >= bounds[0]) & (cand < bounds[1])
+        for idx_child, child_spec in enumerate(filter_s):
+            if idx_child == lead:
+                continue
+            eligible &= _const_membership(
+                seg, child_spec, children[1 + idx_child], safe, num_docs
+            )
+        base = 1 + len(filter_s)
+        for idx_child, child_spec in enumerate(must_not_s):
+            eligible &= ~_const_membership(
+                seg, child_spec, children[base + idx_child], safe, num_docs
+            )
+        scores = score * arrays["boost"]
+        key = jnp.where(eligible, scores, jnp.float32(NEG_INF))
+    # Candidate order ascends by doc id (one span, CSR order).
     kk = min(k, num_docs)
-    kp = min(kk, p)
-    # Candidate order ascends by doc id (one span, CSR order), so
-    # lax.top_k's lowest-index tie-break IS Lucene's doc-id tie-break.
-    top_scores, top_pos = jax.lax.top_k(key, kp)
-    top_ids = cand[top_pos]
-    if kp < kk:
-        top_scores = jnp.pad(top_scores, (0, kk - kp), constant_values=NEG_INF)
-        top_ids = jnp.pad(top_ids, (0, kk - kp), constant_values=0)
-    total = jnp.sum(eligible, dtype=jnp.int32)
-    return top_scores, top_ids.astype(jnp.int32), total
+    return _sparse_top_k(key, cand, eligible, kk, min(kk, p))
 
 
 def _span_locate(flat, start, end, cands):
@@ -1023,20 +1019,30 @@ def _sparse_terms_inner(seg, spec, arrays, k: int, bounds=None):
     length <= total query-term occurrences, bounded by the spec's T_pad
     bucket; top-k positions ascend by doc id, so lax.top_k's lowest-index
     tie-break IS Lucene's doc-id tie-break."""
-    docs_s, run_sum, eligible, p, kk = _sparse_candidates(
-        seg, spec, arrays, k, bounds=bounds
-    )
-    key = jnp.where(eligible, run_sum, jnp.float32(NEG_INF))
-    kp = min(kk, p)
-    top_scores, top_pos = jax.lax.top_k(key, kp)
-    top_ids = docs_s[top_pos]
-    # staticcheck: ignore[traced-branch] kp and kk are Python ints derived from the static spec's worklist shape, not traced values; the branch is resolved at trace time
-    if kp < kk:  # more hits requested than candidate slots: pad
-        top_scores = jnp.pad(
-            top_scores, (0, kk - kp), constant_values=NEG_INF
+    with jax.named_scope(SCOPE_SCORE):
+        docs_s, run_sum, eligible, p, kk = _sparse_candidates(
+            seg, spec, arrays, k, bounds=bounds
         )
-        top_ids = jnp.pad(top_ids, (0, kk - kp), constant_values=0)
-    total = jnp.sum(eligible, dtype=jnp.int32)
+        key = jnp.where(eligible, run_sum, jnp.float32(NEG_INF))
+    return _sparse_top_k(key, docs_s, eligible, kk, min(kk, p))
+
+
+def _sparse_top_k(key, cand, eligible, kk: int, kp: int):
+    """(top scores, top doc ids, total) over candidate `key` scores of the
+    docs `cand`: top-k of kp slots padded out to kk, and the eligible
+    count. Candidate order ascends by doc id, so lax.top_k's lowest-index
+    tie-break IS Lucene's doc-id tie-break."""
+    with jax.named_scope(SCOPE_TOPK):
+        top_scores, top_pos = jax.lax.top_k(key, kp)
+        top_ids = cand[top_pos]
+        # staticcheck: ignore[traced-branch] kp and kk are Python ints derived from the static spec's worklist shape, not traced values; the branch is resolved at trace time
+        if kp < kk:  # more hits requested than candidate slots: pad
+            top_scores = jnp.pad(
+                top_scores, (0, kk - kp), constant_values=NEG_INF
+            )
+            top_ids = jnp.pad(top_ids, (0, kk - kp), constant_values=0)
+    with jax.named_scope(SCOPE_TOTAL):
+        total = jnp.sum(eligible, dtype=jnp.int32)
     return top_scores, top_ids.astype(jnp.int32), total
 
 

@@ -63,6 +63,10 @@ from ..query.compile import (
 from ..query.dsl import Query
 from .routing import shard_for_id
 
+# `jax.named_scope` of the cross-shard merge (all-gather, top-k, psum): a
+# profiler trace carries it in each device op's name metadata.
+SCOPE_REDUCE = "mesh.reduce"
+
 
 def _shard_map(body, mesh: Mesh, in_specs, out_specs):
     """`jax.shard_map` with replication checking off (the reduce mixes
@@ -695,16 +699,17 @@ def sharded_execute(
         global_i = shard_id.astype(jnp.int32) * docs_per_shard + local_i.astype(
             jnp.int32
         )
-        all_s = jax.lax.all_gather(local_s, axis)  # [S, kk]
-        all_i = jax.lax.all_gather(global_i, axis)
-        flat_s = all_s.reshape(-1)
-        flat_i = all_i.reshape(-1)
-        # Merge to min(k, S*kk), not kk: when k exceeds docs_per_shard the
-        # union across shards can still fill k hits (ES returns
-        # min(size, total) hits; the host trims by the psum'd total).
-        top_s, idx = jax.lax.top_k(flat_s, min(k, flat_s.shape[0]))
-        top_i = flat_i[idx]
-        total = jax.lax.psum(count, axis)
+        with jax.named_scope(SCOPE_REDUCE):
+            all_s = jax.lax.all_gather(local_s, axis)  # [S, kk]
+            all_i = jax.lax.all_gather(global_i, axis)
+            flat_s = all_s.reshape(-1)
+            flat_i = all_i.reshape(-1)
+            # Merge to min(k, S*kk), not kk: when k exceeds docs_per_shard
+            # the union across shards can still fill k hits (ES returns
+            # min(size, total) hits; the host trims by the psum'd total).
+            top_s, idx = jax.lax.top_k(flat_s, min(k, flat_s.shape[0]))
+            top_i = flat_i[idx]
+            total = jax.lax.psum(count, axis)
         return top_s, top_i, total
 
     return _shard_map(
@@ -813,17 +818,19 @@ def sharded_execute_request(
                 local_val = top_s
             n_after = jnp.sum(keep, dtype=jnp.int32)
             gids = shard_id * docs_per_shard + ids.astype(jnp.int32)
-            all_key = jax.lax.all_gather(local_key, axis).reshape(-1)
-            all_val = jax.lax.all_gather(local_val, axis).reshape(-1)
-            all_gid = jax.lax.all_gather(gids, axis).reshape(-1)
-            m = min(k, all_key.shape[0])
-            # Stable top-k over -key: equal keys favor the lower flat
-            # index = (shard, per-shard rank) — the host merge tiebreak.
-            neg_top, idxm = jax.lax.top_k(-all_key, m)
-            out_key = -neg_top
-            out_val = all_val[idxm]
-            out_gid = all_gid[idxm]
-            n_after_total = jax.lax.psum(n_after, axis)
+            with jax.named_scope(SCOPE_REDUCE):
+                all_key = jax.lax.all_gather(local_key, axis).reshape(-1)
+                all_val = jax.lax.all_gather(local_val, axis).reshape(-1)
+                all_gid = jax.lax.all_gather(gids, axis).reshape(-1)
+                m = min(k, all_key.shape[0])
+                # Stable top-k over -key: equal keys favor the lower flat
+                # index = (shard, per-shard rank) — the host merge
+                # tiebreak.
+                neg_top, idxm = jax.lax.top_k(-all_key, m)
+                out_key = -neg_top
+                out_val = all_val[idxm]
+                out_gid = all_gid[idxm]
+                n_after_total = jax.lax.psum(n_after, axis)
         else:  # agg-only / count-only request: no hits merge at all
             out_key = jnp.zeros(0, dtype=jnp.float32)
             out_val = jnp.zeros(0, dtype=jnp.float32)
@@ -902,14 +909,15 @@ def sharded_execute_batch(
         local_s, local_i, counts = jax.vmap(one)(arrays)  # [Qb, kk]
         shard_id = jax.lax.axis_index(shard_axis).astype(jnp.int32)
         global_i = shard_id * docs_per_shard + local_i.astype(jnp.int32)
-        all_s = jax.lax.all_gather(local_s, shard_axis)  # [S, Qb, kk]
-        all_i = jax.lax.all_gather(global_i, shard_axis)
-        qb = all_s.shape[1]
-        flat_s = all_s.transpose(1, 0, 2).reshape(qb, -1)  # [Qb, S*kk]
-        flat_i = all_i.transpose(1, 0, 2).reshape(qb, -1)
-        top_s, idx = jax.lax.top_k(flat_s, min(k, flat_s.shape[-1]))
-        top_i = jnp.take_along_axis(flat_i, idx, axis=1)
-        totals = jax.lax.psum(counts, shard_axis)
+        with jax.named_scope(SCOPE_REDUCE):
+            all_s = jax.lax.all_gather(local_s, shard_axis)  # [S, Qb, kk]
+            all_i = jax.lax.all_gather(global_i, shard_axis)
+            qb = all_s.shape[1]
+            flat_s = all_s.transpose(1, 0, 2).reshape(qb, -1)  # [Qb, S*kk]
+            flat_i = all_i.transpose(1, 0, 2).reshape(qb, -1)
+            top_s, idx = jax.lax.top_k(flat_s, min(k, flat_s.shape[-1]))
+            top_i = jnp.take_along_axis(flat_i, idx, axis=1)
+            totals = jax.lax.psum(counts, shard_axis)
         return top_s, top_i, totals
 
     return _shard_map(

@@ -27,6 +27,7 @@ Routes (subset mirroring rest-api-spec/):
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import re
@@ -779,10 +780,8 @@ class RestServer:
         Extra response headers (e.g. Retry-After on shed 429s) land in
         `self._tl.response_headers` for the HTTP layer to emit.
 
-        Every dispatched request runs inside a ROOT trace span: an inbound
-        `traceparent` header continues the caller's W3C trace, and
-        `X-Opaque-Id` tags the root (the reference threads it to tasks and
-        slowlogs the same way). The trace id returns as `X-Trace-Id` +
+        Every dispatched request runs inside the ROOT span `rest.request`
+        (`request_span`). The trace id returns as `X-Trace-Id` +
         `traceparent` response headers."""
         headers = headers or {}
         # QoS lane key for this request, whatever dispatch path follows.
@@ -790,25 +789,7 @@ class RestServer:
             headers.get(self._qos_header)
             or headers.get(self._qos_header.lower())
         )
-        if any(path == p or path.startswith(p + "/") for p in _UNTRACED_PATHS):
-            # Untraced, but still timed: the rolling per-endpoint window
-            # is a few counter words, not a trace-ring slot.
-            t0 = time.monotonic()
-            try:
-                return self._dispatch_inner(method, path, query, body)
-            finally:
-                self._record_latency(method, path, time.monotonic() - t0)
-        tags = {"method": method, "path": path}
-        opaque = headers.get("X-Opaque-Id") or headers.get("x-opaque-id")
-        if opaque:
-            tags["opaque_id"] = opaque
-        with TRACER.start_trace(
-            "rest.request",
-            traceparent=(
-                headers.get("traceparent") or headers.get("Traceparent")
-            ),
-            **tags,
-        ) as root:
+        with self.request_span(method, path, headers) as root:
             t0 = time.monotonic()
             try:
                 status, payload = self._dispatch_inner(
@@ -819,17 +800,52 @@ class RestServer:
                 # health report's serving-latency input
                 # (estpu_rest_latency_recent_ms{endpoint=...}).
                 self._record_latency(method, path, time.monotonic() - t0)
-            root.tags["status"] = status
-            if status >= 500:
-                root.status = "error"
-            self._tl.response_headers = {
-                **getattr(self._tl, "response_headers", {}),
-                "X-Trace-Id": root.trace_id,
-                "traceparent": format_traceparent(
-                    root.trace_id, root.span_id
-                ),
-            }
+            if root is not None:
+                root.tags["status"] = status
+                if status >= 500:
+                    root.status = "error"
+                self._tl.response_headers = {
+                    **getattr(self._tl, "response_headers", {}),
+                    "X-Trace-Id": root.trace_id,
+                    "traceparent": format_traceparent(
+                        root.trace_id, root.span_id
+                    ),
+                }
             return status, payload
+
+    @contextlib.contextmanager
+    def request_span(self, method: str, path: str, headers: dict):
+        """The request's ROOT span `rest.request`, yielded (None when the
+        path is untraced). An inbound `traceparent` header continues the
+        caller's W3C trace, and `X-Opaque-Id` tags the root (the
+        reference threads it to tasks and slowlogs the same way). The
+        HTTP layer opens it around the body read, dispatch and response
+        write; `dispatch()` inside it reuses it, and opens its own when
+        called without the HTTP layer. Paths in _UNTRACED_PATHS reach the
+        profiler and the span-time counters but take no trace-ring slot."""
+        if getattr(self._tl, "request_open", False):
+            yield self._tl.request_root
+            return
+        if any(path == p or path.startswith(p + "/") for p in _UNTRACED_PATHS):
+            handle = TRACER.annotate("rest.request")
+        else:
+            tags = {"method": method, "path": path}
+            opaque = headers.get("X-Opaque-Id") or headers.get("x-opaque-id")
+            if opaque:
+                tags["opaque_id"] = opaque
+            handle = TRACER.start_trace(
+                "rest.request",
+                traceparent=(
+                    headers.get("traceparent") or headers.get("Traceparent")
+                ),
+                **tags,
+            )
+        with handle as root:
+            self._tl.request_open, self._tl.request_root = True, root
+            try:
+                yield root
+            finally:
+                self._tl.request_open, self._tl.request_root = False, None
 
     def _dispatch_inner(
         self, method: str, path: str, query: dict, body: str
@@ -920,6 +936,14 @@ class RestServer:
 
             def _handle(self):
                 parsed = urlparse(self.path)
+                path = parsed.path.rstrip("/") or "/"
+                headers = dict(self.headers.items())
+                # The root span covers the body read and the response
+                # write too, not only dispatch.
+                with rest.request_span(self.command, path, headers):
+                    self._serve(parsed, path, headers)
+
+            def _serve(self, parsed, path, headers):
                 query = {
                     key: vals[0] for key, vals in parse_qs(
                         parsed.query, keep_blank_values=True
@@ -952,8 +976,7 @@ class RestServer:
                 rest._tl.body_nbytes = length
                 body = self.rfile.read(length).decode("utf-8") if length else ""
                 status, payload = rest.dispatch(
-                    self.command, parsed.path.rstrip("/") or "/", query, body,
-                    headers=dict(self.headers.items()),
+                    self.command, path, query, body, headers=headers
                 )
                 if isinstance(payload, PlainText):
                     data = payload.text.encode("utf-8")

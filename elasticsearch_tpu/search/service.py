@@ -824,27 +824,31 @@ class SearchService:
             max_score = None
             if request.sort is None and candidates:
                 max_score = -candidates[0][0]
-            hl_ctx = self._highlight_context(request)
-            for merge_key, global_doc, handle, local, score, sort_value in page:
-                hits.append(
-                    SearchHit(
-                        doc_id=handle.segment.ids[local],
-                        score=score,
-                        source=self._fetch_source(handle, local, request),
-                        sort=(
-                            None
-                            if sort_value is _NO_SORT
-                            else sort_value
-                            if isinstance(sort_value, list)
-                            else [sort_value]
-                        ),
-                        global_doc=global_doc,
-                        highlight=self._fetch_highlight(handle, local, hl_ctx),
-                        fields=self._fetch_fields(handle, local, request),
-                        handle=handle,
-                        local=local,
+            with TRACER.span("search.assemble"):
+                hl_ctx = self._highlight_context(request)
+                for row in page:
+                    _key, global_doc, handle, local, score, sort_value = row
+                    hits.append(
+                        SearchHit(
+                            doc_id=handle.segment.ids[local],
+                            score=score,
+                            source=self._fetch_source(handle, local, request),
+                            sort=(
+                                None
+                                if sort_value is _NO_SORT
+                                else sort_value
+                                if isinstance(sort_value, list)
+                                else [sort_value]
+                            ),
+                            global_doc=global_doc,
+                            highlight=self._fetch_highlight(
+                                handle, local, hl_ctx
+                            ),
+                            fields=self._fetch_fields(handle, local, request),
+                            handle=handle,
+                            local=local,
+                        )
                     )
-                )
         took = int((time.monotonic() - start) * 1000)
         total_out, relation = clamp_total(total, request.track_total_hits)
         profile = None
@@ -964,25 +968,28 @@ class SearchService:
         batch path AND the packed multi-tenant executor, exec/packed.py —
         both score elsewhere and fetch here, so hits/highlights/fields
         render identically to a solo search)."""
-        rows = sorted(rows, key=lambda c: (c[0], c[1]))
-        page = rows[request.from_ : request.from_ + request.size]
-        max_score = -rows[0][0] if rows else None
-        hl_ctx = self._highlight_context(request)
-        hits = []
-        for _key, global_doc, handle, local, score, _sv in page:
-            hits.append(
-                SearchHit(
-                    doc_id=handle.segment.ids[local],
-                    score=score,
-                    source=self._fetch_source(handle, local, request),
-                    sort=None,
-                    global_doc=global_doc,
-                    highlight=self._fetch_highlight(handle, local, hl_ctx),
-                    fields=self._fetch_fields(handle, local, request),
-                    handle=handle,
-                    local=local,
+        with TRACER.span("search.assemble"):
+            rows = sorted(rows, key=lambda c: (c[0], c[1]))
+            page = rows[request.from_ : request.from_ + request.size]
+            max_score = -rows[0][0] if rows else None
+            hl_ctx = self._highlight_context(request)
+            hits = []
+            for _key, global_doc, handle, local, score, _sv in page:
+                hits.append(
+                    SearchHit(
+                        doc_id=handle.segment.ids[local],
+                        score=score,
+                        source=self._fetch_source(handle, local, request),
+                        sort=None,
+                        global_doc=global_doc,
+                        highlight=self._fetch_highlight(
+                            handle, local, hl_ctx
+                        ),
+                        fields=self._fetch_fields(handle, local, request),
+                        handle=handle,
+                        local=local,
+                    )
                 )
-            )
         total_out, relation = clamp_total(total, request.track_total_hits)
         return SearchResponse(
             took_ms=int((time.monotonic() - start) * 1000),
@@ -1048,27 +1055,29 @@ class SearchService:
                     alive.discard(i)
             if not alive:
                 break
-            seg_tree = bm25_device.segment_tree(handle.device)
+            with TRACER.span("device.stage"):
+                seg_tree = bm25_device.segment_tree(handle.device)
             compiled: dict[int, Any] = {}
             req_masks: dict[int, dict] = {}
             for i in sorted(alive):
-                try:
-                    compiled[i] = self.engine.compiler_for(
-                        handle, stats
-                    ).compile(requests[i].query)
-                except ValueError as e:
-                    errors[i] = e
-                    alive.discard(i)
-                    continue
-                # Coalesced batchmates sharing a filter share ONE plane:
-                # substitution happens before grouping, so identical
-                # (spec, plane set) lanes land in the same launch with
-                # the plane passed ONCE via seg["masks"] — never stacked
-                # per lane.
-                compiled[i], req_masks[i] = self._apply_filter_cache(
-                    handle, requests[i].query, compiled[i], seg_tree,
-                    entries=fc_entries[i],
-                )
+                with TRACER.span("query.compile"):
+                    try:
+                        compiled[i] = self.engine.compiler_for(
+                            handle, stats
+                        ).compile(requests[i].query)
+                    except ValueError as e:
+                        errors[i] = e
+                        alive.discard(i)
+                        continue
+                    # Coalesced batchmates sharing a filter share ONE
+                    # plane: substitution happens before grouping, so
+                    # identical (spec, plane set) lanes land in the same
+                    # launch with the plane passed ONCE via seg["masks"]
+                    # — never stacked per lane.
+                    compiled[i], req_masks[i] = self._apply_filter_cache(
+                        handle, requests[i].query, compiled[i], seg_tree,
+                        entries=fc_entries[i],
+                    )
             from ..index.filter_cache import mask_group_token
 
             groups: dict[tuple, list[int]] = {}
@@ -1176,64 +1185,73 @@ class SearchService:
             from ..exec.cost import PlanFeatures
             from ..exec.planner import oracle_eligible, spec_work_tiles
 
-            if all(oracle_eligible(requests[i].query) for i in rows):
-                plan_class = ("batched", spec, k_max)
-                feats = PlanFeatures(
-                    n_docs=handle.segment.num_docs,
-                    work_tiles=(
-                        spec_work_tiles(spec)
-                        if bm25_device.supports_sparse(spec)
-                        else 0
-                    ),
-                )
-                backend = self.planner.decide(
-                    plan_class, ["device_batched", "oracle"], feats
-                )
+            with TRACER.span("exec.plan"):
+                if all(oracle_eligible(requests[i].query) for i in rows):
+                    plan_class = ("batched", spec, k_max)
+                    feats = PlanFeatures(
+                        n_docs=handle.segment.num_docs,
+                        work_tiles=(
+                            spec_work_tiles(spec)
+                            if bm25_device.supports_sparse(spec)
+                            else 0
+                        ),
+                    )
+                    backend = self.planner.decide(
+                        plan_class, ["device_batched", "oracle"], feats
+                    )
         if backend == "oracle":
-            from .oracle import OracleSearcher
+            # One span for the whole branch: the oracle is built once per
+            # group, then serves its lanes one by one.
+            with TRACER.span("search.oracle"):
+                from .oracle import OracleSearcher
 
-            oracle = OracleSearcher(
-                handle.segment,
-                self.engine.mappings,
-                self.engine.params,
-                stats=stats,  # the compiler's pushed-down scope, verbatim
-                live=self._host_live(handle),
-            )
-            remaining = list(rows)
-            while remaining:
-                i = remaining[0]
-                lane_t0 = time.monotonic()
-                try:
-                    scores, ids, tot = oracle.search(requests[i].query, ks[i])
-                # staticcheck: ignore[broad-except] oracle gap falls back to the device; the numpy oracle polls no tasks and hosts no fault sites
-                except Exception:
-                    # Same contract as the single-request path: an oracle
-                    # gap falls back to the device (for every lane not yet
-                    # served) instead of failing the whole batch, and the
-                    # penalty observation stops the planner from retrying
-                    # the oracle for this class.
-                    if plan_class is not None:
-                        # observe (not record): a failed attempt is a
-                        # cost sample, not a served decision.
-                        self.planner.cost.observe(
-                            plan_class, "oracle",
-                            self.FAILED_BACKEND_PENALTY_S,
-                        )
-                    self._device_batch(
-                        handle, spec, remaining, compiled, ks, k_max,
-                        plan_class, cands, totals, seg_tree=seg_tree,
-                        masks=masks,
-                    )
-                    return
-                remaining.pop(0)
-                self._append_plain(
-                    cands[i], handle, scores, ids, min(ks[i], len(ids))
+                oracle = OracleSearcher(
+                    handle.segment,
+                    self.engine.mappings,
+                    self.engine.params,
+                    # the compiler's pushed-down scope, verbatim
+                    stats=stats,
+                    live=self._host_live(handle),
                 )
-                totals[i] += int(tot)
-                if plan_class is not None:
-                    self.planner.record(
-                        plan_class, "oracle", time.monotonic() - lane_t0
+                remaining = list(rows)
+                while remaining:
+                    i = remaining[0]
+                    lane_t0 = time.monotonic()
+                    try:
+                        scores, ids, tot = oracle.search(
+                            requests[i].query, ks[i]
+                        )
+                    # staticcheck: ignore[broad-except] oracle gap falls back to the device; the numpy oracle polls no tasks and hosts no fault sites
+                    except Exception:
+                        # Same contract as the single-request path: an
+                        # oracle gap falls back to the device (for every
+                        # lane not yet served) instead of failing the whole
+                        # batch, and the penalty observation stops the
+                        # planner from retrying the oracle for this class.
+                        if plan_class is not None:
+                            # observe (not record): a failed attempt is a
+                            # cost sample, not a served decision.
+                            self.planner.cost.observe(
+                                plan_class, "oracle",
+                                self.FAILED_BACKEND_PENALTY_S,
+                            )
+                        self._device_batch(
+                            handle, spec, remaining, compiled, ks, k_max,
+                            plan_class, cands, totals, seg_tree=seg_tree,
+                            masks=masks,
+                        )
+                        return
+                    remaining.pop(0)
+                    self._append_plain(
+                        cands[i], handle, scores, ids,
+                        min(ks[i], len(ids)),
                     )
+                    totals[i] += int(tot)
+                    if plan_class is not None:
+                        self.planner.record(
+                            plan_class, "oracle",
+                            time.monotonic() - lane_t0,
+                        )
         else:
             self._device_batch(
                 handle, spec, rows, compiled, ks, k_max, plan_class, cands,
@@ -1270,11 +1288,12 @@ class SearchService:
                 )
                 totals[i] += tot
             return
-        arrays_b = jax.tree.map(
-            lambda *xs: np.stack(xs), *[compiled[i].arrays for i in rows]
-        )
-        if self.device is not None:
-            self.device.h2d(arrays_b)
+        with TRACER.span("device.stage"):
+            arrays_b = jax.tree.map(
+                lambda *xs: np.stack(xs), *[compiled[i].arrays for i in rows]
+            )
+            if self.device is not None:
+                self.device.h2d(arrays_b)
         kernel = (
             bm25_device.execute_batch_sparse
             if bm25_device.supports_sparse(spec)
@@ -1290,20 +1309,25 @@ class SearchService:
             (spec, k_max, "device_batched"),
             "device_batched",
         ) as tl:
-            out = tl.dispatched(kernel(seg_tree, spec, arrays_b, k_max))
-        s_b, i_b, t_b = jax.device_get(out)
-        elapsed = time.monotonic() - t0
-        for row, i in enumerate(rows):
-            tot = int(t_b[row])
-            nn = min(ks[i], tot, s_b.shape[1])
-            self._append_plain(cands[i], handle, s_b[row], i_b[row], nn)
-            totals[i] += tot
-            if plan_class is not None:
-                # Amortized per-lane cost: what this class actually
-                # pays per query when batched.
-                self.planner.record(
-                    plan_class, "device_batched", elapsed / len(rows)
-                )
+            # Argument transfer, jit cache lookup and enqueue; the wait on
+            # the device is `device.wait`, inside dispatched().
+            with TRACER.span("device.dispatch"):
+                out = kernel(seg_tree, spec, arrays_b, k_max)
+            out = tl.dispatched(out)
+        with TRACER.span("device.fetch"):
+            s_b, i_b, t_b = jax.device_get(out)
+            elapsed = time.monotonic() - t0
+            for row, i in enumerate(rows):
+                tot = int(t_b[row])
+                nn = min(ks[i], tot, s_b.shape[1])
+                self._append_plain(cands[i], handle, s_b[row], i_b[row], nn)
+                totals[i] += tot
+                if plan_class is not None:
+                    # Amortized per-lane cost: what this class actually
+                    # pays per query when batched.
+                    self.planner.record(
+                        plan_class, "device_batched", elapsed / len(rows)
+                    )
 
     @staticmethod
     def _append_plain(bucket, handle, scores, ids, n) -> None:
@@ -1787,15 +1811,18 @@ class SearchService:
         # (faults/registry.py `search.kernel`).
         fault_point("search.kernel", index=self.index_name)
         plan_t0 = time.monotonic()
-        compiler = self.engine.compiler_for(handle, stats)
-        compiled = compiler.compile(request.query)
-        seg_tree = bm25_device.segment_tree(handle.device)
-        # Filter cache: swap cacheable filter-context clauses for their
-        # cached (or freshly admitted) mask planes — bit-identical by
-        # construction, the plane IS the clause's own evaluation.
-        compiled, fc_masks = self._apply_filter_cache(
-            handle, request.query, compiled, seg_tree, entries=fc_entries
-        )
+        with TRACER.span("device.stage"):
+            seg_tree = bm25_device.segment_tree(handle.device)
+        with TRACER.span("query.compile"):
+            compiler = self.engine.compiler_for(handle, stats)
+            compiled = compiler.compile(request.query)
+            # Filter cache: swap cacheable filter-context clauses for
+            # their cached (or freshly admitted) mask planes —
+            # bit-identical by construction, the plane IS the clause's
+            # own evaluation.
+            compiled, fc_masks = self._apply_filter_cache(
+                handle, request.query, compiled, seg_tree, entries=fc_entries
+            )
         if fc_masks:
             seg_tree = {**seg_tree, "masks": fc_masks}
         now = time.monotonic()
@@ -1810,7 +1837,8 @@ class SearchService:
         h2d_bytes = 0
         if self.device is not None:
             # Host→device plan-array bytes this launch stages.
-            h2d_bytes = self.device.h2d(compiled.arrays)
+            with TRACER.span("device.stage"):
+                h2d_bytes = self.device.h2d(compiled.arrays)
 
         def done(total: int, backend: str = "device") -> tuple[int, str]:
             elapsed = time.monotonic() - exec_t0
@@ -1896,9 +1924,11 @@ class SearchService:
                 # candidate backend returns identical top-k/totals.
                 plan_class = None
                 if self.planner is not None and not request.rescore:
-                    backend, plan_class = self._decide_backend(
-                        handle, request, compiled, k, masked=bool(fc_masks)
-                    )
+                    with TRACER.span("exec.plan"):
+                        backend, plan_class = self._decide_backend(
+                            handle, request, compiled, k,
+                            masked=bool(fc_masks),
+                        )
                     # The routing decision, as a tagged event on the
                     # enclosing segment span.
                     TRACER.event(
@@ -1912,13 +1942,14 @@ class SearchService:
                     from .oracle import OracleSearcher
 
                     try:
-                        scores, ids, tot = OracleSearcher(
-                            handle.segment,
-                            self.engine.mappings,
-                            self.engine.params,
-                            stats=stats,
-                            live=self._host_live(handle),
-                        ).search(request.query, k)
+                        with TRACER.span("search.oracle"):
+                            scores, ids, tot = OracleSearcher(
+                                handle.segment,
+                                self.engine.mappings,
+                                self.engine.params,
+                                stats=stats,
+                                live=self._host_live(handle),
+                            ).search(request.query, k)
                     # staticcheck: ignore[broad-except] oracle gap falls back to the device; the numpy oracle polls no tasks and hosts no fault sites
                     except Exception:
                         # Defensive: an oracle gap falls back to the
@@ -1947,10 +1978,13 @@ class SearchService:
                     )
                     scores, ids, tot = s[0], i[0], int(t[0])
                 elif backend in ("device", "cached_mask"):
-                    scores, ids, tot = bm25_device.execute_auto(
-                        seg_tree, compiled.spec, compiled.arrays, fetch_k
-                    )
-                    scores, ids = np.asarray(scores), np.asarray(ids)
+                    with TRACER.span("device.dispatch"):
+                        scores, ids, tot = bm25_device.execute_auto(
+                            seg_tree, compiled.spec, compiled.arrays, fetch_k
+                        )
+                    # Unbatched, the wait on the device is in the fetch.
+                    with TRACER.span("device.fetch"):
+                        scores, ids = np.asarray(scores), np.asarray(ids)
                     if request.rescore:
                         scores, ids = self._apply_rescore(
                             handle, seg_tree, request, scores, ids, int(tot),

@@ -187,7 +187,10 @@ def main() -> int:
         f"(capture {stop['duration_ms']:.0f}ms; load the .trace.json.gz "
         f"at ui.perfetto.dev)"
     )
-    print(f"obs trace ring id: {stop['trace_id']} (GET /_traces/<id>)")
+    print(
+        "request work in the capture: host events named estpu/<span> "
+        "(rest.request, search, batcher.launch, device.wait, ...)"
+    )
     return 0
 
 

@@ -11,7 +11,9 @@ nonzero took; slowlog lines carry trace_id + took_breakdown.
 
 import json
 import logging
+import os
 import threading
+import time
 
 import pytest
 
@@ -19,6 +21,7 @@ from elasticsearch_tpu.faults import REGISTRY, FaultSpec
 from elasticsearch_tpu.node import Node
 from elasticsearch_tpu.obs.tracing import (
     TRACER,
+    Tracer,
     format_traceparent,
     parse_traceparent,
 )
@@ -419,6 +422,213 @@ class TestLocalTrace:
         ):
             node.index_doc("w2", {"b": "x"}, "d1")
         assert caplog.records
+
+
+class TestSpanCounters:
+    def test_self_time_excludes_spans_nested_on_the_thread(self):
+        tracer = Tracer()
+        with tracer.annotate("outer"):
+            time.sleep(0.02)
+            with tracer.span("inner"):  # untraced: counters, no ring
+                time.sleep(0.03)
+            seen = []
+            worker = threading.Thread(  # another thread: not nested
+                target=lambda: seen.append(tracer.annotate("other"))
+            )
+            worker.start()
+            worker.join()
+            with seen[0]:
+                pass
+        with tracer.start_trace("root"):
+            pass
+        counts = tracer.metrics.label_values("estpu_spans_total", "span")
+        wall = tracer.metrics.label_values("estpu_span_seconds_total", "span")
+        own = tracer.metrics.label_values(
+            "estpu_span_self_seconds_total", "span"
+        )
+        assert counts == {"outer": 1, "inner": 1, "other": 1, "root": 1}
+        assert wall["inner"] >= 0.03 and own["inner"] == wall["inner"]
+        assert wall["outer"] >= 0.05
+        assert own["outer"] == pytest.approx(
+            wall["outer"] - wall["inner"] - wall["other"]
+        )
+        # Only the traced root reached the ring.
+        assert [t["root"] for t in tracer.traces()] == ["root"]
+
+    def test_concurrent_spans_lose_no_count(self):
+        """Threads that open their first spans of a name at once share one
+        series and lose no update."""
+        import sys
+
+        tracer = Tracer()
+        n_threads, per_thread = 16, 500
+        barrier = threading.Barrier(n_threads)
+
+        def work():
+            barrier.wait(timeout=30)
+            for _ in range(per_thread):
+                with tracer.annotate("outer"):
+                    with tracer.span("inner"):
+                        pass
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=work) for _ in range(n_threads)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        counts = tracer.metrics.label_values("estpu_spans_total", "span")
+        assert counts == {
+            "outer": n_threads * per_thread,
+            "inner": n_threads * per_thread,
+        }
+        own = tracer.metrics.label_values(
+            "estpu_span_self_seconds_total", "span"
+        )
+        wall = tracer.metrics.label_values("estpu_span_seconds_total", "span")
+        # Self time never counts another thread's nested span.
+        assert own["outer"] == pytest.approx(
+            wall["outer"] - wall["inner"], abs=1e-6
+        )
+
+    def test_rest_metrics_expose_span_time(self):
+        rest = RestServer()
+        try:
+            status, _ = rest.dispatch("GET", "/_cluster/health", {}, "")
+            assert status == 200
+            status, text = rest.dispatch("GET", "/_metrics", {}, "")
+        finally:
+            rest.close()
+        assert status == 200
+        assert 'estpu_spans_total{span="rest.request"}' in text.text
+        assert 'estpu_span_self_seconds_total{span="rest.request"}' in (
+            text.text
+        )
+
+
+def _host_lines(trace_dir) -> list[list[tuple[str, float, float]]]:
+    """`estpu/` host events of a jax.profiler capture, one list per line
+    of the `/host:CPU` plane — one line per thread, keyed by position
+    (every Python thread's line is named `python`)."""
+    from jax.profiler import ProfileData
+
+    path = next(
+        os.path.join(root, f)
+        for root, _d, files in os.walk(trace_dir)
+        for f in files
+        if f.endswith(".xplane.pb")
+    )
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            return [
+                [
+                    (
+                        ev.name[len("estpu/"):],
+                        float(ev.start_ns),
+                        float(ev.start_ns + ev.duration_ns),
+                    )
+                    for ev in line.events
+                    if ev.name.startswith("estpu/")
+                ]
+                for line in plane.lines
+            ]
+    return []
+
+
+class TestProfilerSpans:
+    """The batched `_search` path in a jax.profiler capture: every step
+    is an `estpu/<span>` host event, nested on its own thread."""
+
+    # (child, parent) on the HTTP handler's thread, then on the
+    # micro-batcher's scheduler thread.
+    HANDLER = (("search", "rest.request"), ("batcher.await", "search"))
+    SCHEDULER = tuple(
+        (name, "batcher.launch")
+        for name in (
+            "query.compile", "exec.plan", "search.oracle", "device.stage",
+            "device.dispatch", "device.wait", "device.fetch",
+            "search.assemble",
+        )
+    )
+
+    def test_batched_search_spans_nest_per_thread(
+        self, monkeypatch, tmp_path
+    ):
+        import http.client
+
+        import jax
+
+        from elasticsearch_tpu.rest.server import create_server
+
+        monkeypatch.setenv("ESTPU_MESH_SERVING", "0")
+        monkeypatch.setenv("ESTPU_EXEC_PACKED", "0")  # SearchService lanes
+        http_server, rest = create_server(port=0)
+        serving = threading.Thread(
+            target=http_server.serve_forever, daemon=True
+        )
+        serving.start()
+        conn = http.client.HTTPConnection(
+            "127.0.0.1", http_server.server_address[1], timeout=120
+        )
+        body = json.dumps({"query": {"match": {"b": "alpha w1"}}})
+
+        def search():
+            conn.request(
+                "POST", "/cap/_search", body,
+                {"Content-Type": "application/json"},
+            )
+            resp = json.loads(conn.getresponse().read())
+            hits = resp["hits"]
+            return hits["total"], [
+                (h["_id"], h["_score"]) for h in hits["hits"]
+            ]
+
+        try:
+            _seed(rest, index="cap", shards=1, replicas=0)
+            # The planner explores both backends of the plan class (the
+            # CPU oracle and the device launch) within these searches.
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                captured = [search() for _ in range(5)]
+            finally:
+                jax.profiler.stop_trace()
+            plain = [search() for _ in range(5)]
+        finally:
+            conn.close()
+            http_server.shutdown()
+            http_server.server_close()
+            rest.close()
+        assert captured == plain and captured[0][1]
+
+        lines = [ln for ln in _host_lines(tmp_path) if ln]
+        names = {name for ln in lines for name, _s, _e in ln}
+        for child, parent in self.HANDLER + self.SCHEDULER:
+            assert {child, parent} <= names, (child, parent, names)
+        handler = [
+            ln for ln in lines if any(n == "rest.request" for n, *_ in ln)
+        ]
+        scheduler = [
+            ln for ln in lines if any(n == "batcher.launch" for n, *_ in ln)
+        ]
+        assert handler and scheduler
+        assert not any(ln in scheduler for ln in handler)
+        for group, pairs in (
+            (handler, self.HANDLER), (scheduler, self.SCHEDULER)
+        ):
+            for ln in group:
+                for child, parent in pairs:
+                    for name, s, e in ln:
+                        if name != child:
+                            continue
+                        assert any(
+                            n == parent and ps <= s and e <= pe
+                            for n, ps, pe in ln
+                        ), f"{child} at {s} outside every {parent}"
 
 
 class TestTasksApi:
