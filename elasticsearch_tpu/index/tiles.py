@@ -31,6 +31,7 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field as dc_field
 from typing import Any
 
@@ -161,6 +162,11 @@ class DeviceField:
         if tid is None:
             return 0
         return int(self.df[tid])
+
+    @functools.cached_property
+    def longest_span(self) -> int:
+        """Postings of the field's longest term span (host planning)."""
+        return int(np.diff(self.offsets).max()) if self.num_terms else 0
 
 
 @dataclass

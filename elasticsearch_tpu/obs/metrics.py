@@ -732,6 +732,11 @@ CATALOG = {
     "estpu_device_actual_tiles_total": ("counter", "device"),
     "estpu_device_padding_waste_ratio": ("histogram", "device"),
     "estpu_device_blockmax_pruned_tile_fraction": ("histogram", "device"),
+    # Scored disjunctions of 2+ terms by MaxScore outcome (ops/bm25_device.
+    # execute_maxscore): served by the kernel, re-run exhaustively after
+    # its threshold check failed, MaxScore plans the exec planner routed to
+    # the CPU oracle, or disjunctions not on the MaxScore plan (bypass).
+    "estpu_sparse_maxscore_total": ("counter", "device"),
     # Device observability (ISSUE 14, obs/device.py): per-launch wall
     # times split queue (dispatch return) vs execute (block_until_ready)
     # per backend/plan class — the split is honest only on real devices
@@ -1182,6 +1187,15 @@ class DeviceInstruments:
         launch site (ops/bm25_device.execute_batch_blockmax[_conj])."""
         self._prune_hist().observe(min(1.0, max(0.0, float(fraction))))
 
+    def maxscore(self, outcome: str, n: int = 1) -> None:
+        """Count `n` scored disjunctions by MaxScore outcome: `served`,
+        `fallback`, `oracle` or `bypass` (search/service.py)."""
+        self.registry.counter(
+            "estpu_sparse_maxscore_total",
+            "Scored term disjunctions by MaxScore outcome",
+            outcome=outcome,
+        ).inc(float(n))
+
     def _prune_hist(self) -> Histogram:
         return self.registry.histogram(
             "estpu_device_blockmax_pruned_tile_fraction",
@@ -1295,6 +1309,14 @@ class DeviceInstruments:
             ),
             "padding_waste_pct": self.padding_waste_pct(),
             "blockmax_pruned_tile_fraction": self._prune_summary(),
+            "maxscore": {
+                k: int(v)
+                for k, v in sorted(
+                    self.registry.label_values(
+                        "estpu_sparse_maxscore_total", "outcome"
+                    ).items()
+                )
+            },
             # Retrace census (ISSUE 14): real attributed XLA compiles +
             # the top-N recompiling classes — `device.compile`.
             "compile": self.compile_census(),

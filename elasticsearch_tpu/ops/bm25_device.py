@@ -1304,6 +1304,166 @@ def execute_auto(seg, spec, arrays, k: int):
 
 
 # ---------------------------------------------------------------------------
+# MaxScore disjunction (Turtle & Flood, 1995; Lucene scores disjunctions
+# the same way once the collector's threshold lets it skip, TopDocs-
+# CollectorContext.java:68). The plan (query/compile._maxscore_arrays)
+# splits the terms into essential ones, whose posting tiles form the
+# worklist, and non-essential ones N, which no doc matching only them can
+# score past `ub_n`. Candidates are the essential postings' docs; each is
+# scored exactly — essential contributions from its sorted run, N's by a
+# search of each N span — and folded in query-term order, the
+# accumulation order of the exhaustive kernel and the oracle. When the
+# k-th candidate score θ beats `ub_n` (with the fp32 margin
+# execute_batch_blockmax uses), no skipped doc can enter the top k, and
+# ids and scores are exact. N's postings, nearly all of a stop-word
+# query's, are never read.
+#
+# spec: ("terms_maxscore", field, NT, T_pad, R), R a static bound on the
+#       tile rows of any span of the segment (see _locate_in_spans)
+# arrays: {"tile_ids", "starts", "ends", "term_of": i32[NT],
+#          "weights": f32[NT],          — the essential worklist
+#          "n_term", "n_starts", "n_ends": i32[N_pad],
+#          "n_weights": f32[N_pad],     — N's spans (n_term T_pad = pad)
+#          "ub_n": f32[], "total_floor": i32[] — the largest term span}
+# Returns (top scores, top ids, total, ok): total = max(candidates,
+# total_floor), a lower bound on the matches that exceeds the request's
+# track_total_hits threshold; `ok` false means θ did not beat ub_n and
+# the answer must come from the exhaustive plan instead.
+# ---------------------------------------------------------------------------
+
+
+def supports_maxscore(spec) -> bool:
+    """Is this a MaxScore plan (query/compile.Compiler.compile_maxscore)?"""
+    return isinstance(spec, tuple) and bool(spec) and spec[0] == "terms_maxscore"
+
+
+def _locate_in_spans(doc_tiles, starts, ends, cands, fence_rows: int):
+    """(found, row, col), each [S, P]: where each candidate doc sits in
+    each of S sorted posting spans [starts[s], ends[s]) of a tiled plane.
+
+    On the TPU a gather costs about the same per index whether it reads
+    one value or a whole tile row, so a binary search of ~20 scalar
+    gathers per candidate is the slow way. Instead the first doc of each
+    of the span's tile rows (at most `fence_rows`, a static bound) is
+    sliced out once as a fence, every candidate counts the fence entries
+    at or below it (no gather) to find its row, and one row gather per
+    candidate and span settles it."""
+    n_rows = doc_tiles.shape[0]
+    fence_rows = min(fence_rows, n_rows)
+    iota = jnp.arange(TILE, dtype=jnp.int32)[None, :]
+    found, rows, cols = [], [], []
+    for s in range(starts.shape[0]):
+        start, end = starts[s], ends[s]
+        t0, t1 = start // TILE, (end - 1) // TILE
+        row0 = jnp.clip(t0, 0, n_rows - fence_rows)
+        first = jax.lax.dynamic_slice(
+            doc_tiles, (row0, jnp.int32(0)), (fence_rows, 1)
+        )[:, 0]
+        row_ids = row0 + jnp.arange(fence_rows, dtype=jnp.int32)
+        # The span's first row may open with another term's postings.
+        fence = jnp.where(row_ids == t0, jnp.int32(-1), first)
+        fence = jnp.where(
+            (row_ids >= t0) & (row_ids <= t1), fence, jnp.iinfo(jnp.int32).max
+        )
+        below = jnp.sum(
+            fence[None, :] <= cands[:, None], axis=1, dtype=jnp.int32
+        )
+        row = jnp.clip(t0 + below - 1, 0, n_rows - 1)
+        pos = row[:, None] * TILE + iota
+        hit = (
+            (doc_tiles[row] == cands[:, None]) & (pos >= start) & (pos < end)
+        )
+        found.append(jnp.any(hit, axis=1))
+        rows.append(row)
+        cols.append(jnp.argmax(hit, axis=1).astype(jnp.int32))
+    return jnp.stack(found), jnp.stack(rows), jnp.stack(cols)
+
+
+def _maxscore_inner(seg, spec, arrays, k: int):
+    _, field_name, _nt, t_pad, fence_rows = spec
+    n_pad = arrays["n_term"].shape[0]
+    live = seg["live"]
+    num_docs = live.shape[0]
+    one = jnp.float32(1.0)
+    zero = jnp.float32(0.0)
+    with jax.named_scope(SCOPE_SCORE):
+        docs, tn, valid, _norm = _gather_tiles(spec, arrays, seg)
+        w = arrays["weights"][:, None]
+        sentinel = jnp.int32(num_docs)
+        docs = jnp.where(valid, docs, sentinel).reshape(-1)
+        contrib = jnp.where(valid, w - w / (one + tn), zero).reshape(-1)
+        term = jnp.where(
+            valid, arrays["term_of"][:, None], jnp.int32(t_pad)
+        ).reshape(-1)
+        p = docs.shape[0]
+        docs_s, term_s, contrib_s = jax.lax.sort(
+            (docs, term, contrib), num_keys=1
+        )
+        # per[i, j]: term j's contribution to the doc of position i, read
+        # from the doc's run (at most one posting per term occurrence, so
+        # each sum has one non-zero addend at most and is exact).
+        terms_iota = jnp.arange(t_pad, dtype=jnp.int32)[None, :]
+        docs_ext = jnp.concatenate(
+            [docs_s, jnp.full(t_pad, num_docs + 1, dtype=docs_s.dtype)]
+        )
+        term_ext = jnp.concatenate(
+            [term_s, jnp.full(t_pad, t_pad, dtype=term_s.dtype)]
+        )
+        contrib_ext = jnp.concatenate(
+            [contrib_s, jnp.zeros(t_pad, dtype=contrib_s.dtype)]
+        )
+        per = jnp.zeros((p, t_pad), dtype=jnp.float32)
+        for r in range(t_pad):
+            hit = (docs_ext[r : r + p] == docs_s)[:, None] & (
+                term_ext[r : r + p][:, None] == terms_iota
+            )
+            per = per + jnp.where(hit, contrib_ext[r : r + p][:, None], zero)
+        safe = jnp.minimum(docs_s, sentinel - 1)
+        doc_tiles, tn_tiles = seg["fields"][field_name][:2]
+        found, rows, cols = _locate_in_spans(
+            doc_tiles, arrays["n_starts"], arrays["n_ends"], safe, fence_rows
+        )
+        nw = arrays["n_weights"][:, None]
+        n_tn = tn_tiles[rows, cols]
+        n_contrib = jnp.where(found, nw - nw / (one + n_tn), zero)
+        for s in range(n_pad):
+            per = per + jnp.where(
+                arrays["n_term"][s] == terms_iota, n_contrib[s][:, None], zero
+            )
+        score = jnp.zeros(p, dtype=jnp.float32)
+        for j in range(t_pad):  # left fold in query-term order
+            score = score + per[:, j]
+        is_start = jnp.concatenate(
+            [jnp.ones(1, dtype=bool), docs_s[1:] != docs_s[:-1]]
+        )
+        eligible = is_start & (docs_s != sentinel) & live[safe]
+        key = jnp.where(eligible, score, jnp.float32(NEG_INF))
+    kk = min(k, num_docs)
+    top_scores, top_ids, n_cand = _sparse_top_k(
+        key, docs_s, eligible, kk, min(kk, p)
+    )
+    with jax.named_scope(SCOPE_TOTAL):
+        total = jnp.maximum(n_cand, arrays["total_floor"])
+        margin = arrays["ub_n"] * jnp.float32(1 + 1e-6) + jnp.float32(1e-6)
+        ok = top_scores[kk - 1] > margin
+    return top_scores, top_ids, total, ok
+
+
+@partial(jax.jit, static_argnames=("spec", "k"))
+def execute_maxscore(seg, spec, arrays, k: int):
+    """One MaxScore disjunction (see the block comment above)."""
+    return _maxscore_inner(seg, spec, arrays, k)
+
+
+@partial(jax.jit, static_argnames=("spec", "k"))
+def execute_batch_maxscore(seg, spec, arrays_batched, k: int):
+    """Batched MaxScore disjunctions ([Q, ...] plan arrays)."""
+    return jax.vmap(lambda arrays: _maxscore_inner(seg, spec, arrays, k))(
+        arrays_batched
+    )
+
+
+# ---------------------------------------------------------------------------
 # After-cursor execution (search_after / scroll continuation).
 #
 # The cursor is (after_key, after_doc): a doc qualifies when its key is

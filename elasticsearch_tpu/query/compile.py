@@ -113,6 +113,14 @@ def _pow2(n: int, minimum: int = 1) -> int:
     return 1 << (n - 1).bit_length()
 
 
+def _scope_df(dfield: DeviceField, term: str, stats: FieldStats | None) -> int:
+    """A term's document frequency in the statistics scope (DFS override,
+    else the segment's own)."""
+    if stats:
+        return stats.df.get(term, dfield.term_df(term))
+    return dfield.term_df(term)
+
+
 def _f32_range_bounds(gte, gt, lte, lt) -> tuple[np.float32, np.float32]:
     """Inclusive f32 [lo, hi] for a range over an f32-quantized column.
 
@@ -145,6 +153,7 @@ def _terms_arrays(
     scored: bool,
     nt_floor: int = 1,
     doc_range: tuple[int, int] | None = None,
+    tpad_floor: int = 1,
 ) -> tuple[tuple, dict]:
     """Lower a term disjunction to a flat tile worklist.
 
@@ -190,11 +199,7 @@ def _terms_arrays(
     sel_df = 0
     for term in terms:
         s, e = dfield.term_span(term)
-        df = (
-            stats.df.get(term, dfield.term_df(term))
-            if stats
-            else dfield.term_df(term)
-        )
+        df = _scope_df(dfield, term, stats)
         sel_df += max(0, int(df))
         w = 0.0
         if scored and df > 0 and doc_count > 0:
@@ -252,7 +257,7 @@ def _terms_arrays(
         # T_pad bounds candidates per doc (= total term occurrences; each
         # occurrence yields at most one posting per doc), pow-2 bucketed —
         # the sparse kernel's run-fold length (ops/bm25_device.py).
-        spec = (kind, dfield.name, nt, _pow2(len(terms)))
+        spec = (kind, dfield.name, nt, _pow2(len(terms), tpad_floor))
     elif len(terms) == 1:
         # Single-term constant filter: the spec's trailing 1 marks that
         # the whole worklist is ONE contiguous posting span, so the
@@ -276,7 +281,7 @@ def _terms_arrays(
         arrays["weights"] = weights
         arrays["ub"] = ubs
         arrays["ub_other"] = ub_other
-        t_pad = _pow2(len(terms))
+        t_pad = _pow2(len(terms), tpad_floor)
         term_starts = np.zeros(t_pad, dtype=np.int32)
         term_ends = np.zeros(t_pad, dtype=np.int32)
         term_weights = np.zeros(t_pad, dtype=np.float32)
@@ -296,6 +301,148 @@ def _terms_arrays(
             arrays["cache"] = cache
     else:
         arrays["boost"] = np.float32(boost)
+    return spec, arrays
+
+
+# Shape buckets of MaxScore traffic (Compiler.compile_maxscore). Every
+# distinct spec is a program to compile and a plan class the exec planner
+# prices from its first observations, so the buckets are few: the
+# essential worklist (a few to a few dozen tiles for rare terms) pads to
+# 64 tiles, the term count to 16, and at most two terms are
+# non-essential. The kernel compares every candidate with the row heads
+# of each non-essential span, so its cost grows with the worklist. On a
+# v5e, 2.2M docs, 16384 row heads, one launch: 4.5 ms at 64 tiles, 10 at
+# 256, 18 at 512, 34 at 1024, 67-71 at 2048, against 50 ms for the padded
+# exhaustive plan of the same query. Past MAXSCORE_NT_MAX tiles the
+# lowering declines: a 1024-tile bucket would still win, but it is one
+# more program (18-27 s to compile there) for a sliver of the traffic,
+# which then shares the exhaustive plan's one shape instead.
+MAXSCORE_NT_FLOOR = 64
+MAXSCORE_NT_MAX = 512
+MAXSCORE_TPAD = 16
+MAXSCORE_NPAD = 2
+
+
+def span_rows_bucket(dfield: DeviceField) -> int:
+    """Pow-2 bound on the tile rows any one term's postings cover in this
+    segment: its longest span, misaligned. One value per segment, so the
+    MaxScore kernel's non-essential lookups (and the exhaustive plans of
+    MaxScore traffic) compile once per segment, not per query."""
+    return _pow2((max(dfield.longest_span, 1) - 1) // TILE + 2)
+
+
+def _maxscore_arrays(
+    dfield: DeviceField,
+    terms: list[str],
+    boost: float,
+    params: BM25Params,
+    stats: FieldStats | None,
+    threshold: int,
+) -> tuple[tuple, dict] | None:
+    """Lower a scored term disjunction for the MaxScore kernel, or None.
+
+    MaxScore (Turtle & Flood, 1995) splits a disjunction into essential
+    terms, whose postings generate the candidates, and non-essential terms
+    N, whose summed upper bound `ub_n` no document matching only them can
+    exceed. The kernel (ops/bm25_device.execute_maxscore) scores every
+    candidate exactly, N's contributions looked up in N's spans, and its
+    answer is exact whenever the k-th candidate score beats `ub_n`.
+
+    The split uses observable statistics only: each term's postings in
+    this segment and its upper bound `w - w / (1 + max tile_max)` over its
+    tiles (a numpy slice max). Terms join N in ascending bound order while
+    (a) the term's postings exceed `threshold` (the request's
+    track_total_hits), so the reported total, at least the largest span,
+    still clamps to what exact counting would report, (b) N's summed bound
+    stays within a third of the largest term bound: the k-th candidate
+    scores well above that when the strongest term has k docs, so a check
+    that fails (and sends the query to the exhaustive plan) stays rare,
+    and a mid-frequency term whose bound is close to the rare terms' stays
+    essential, and (c) N holds fewer than MAXSCORE_NPAD terms. The
+    largest-bound term is always essential; a term with no postings here
+    takes no side. None when N would be empty or the essential worklist
+    would pass MAXSCORE_NT_MAX tiles. The caller has checked that the
+    segment's precomputed impacts apply (statistics scope, k1, b).
+
+    Only the essential terms' tiles are enumerated; N's are never touched,
+    on the host or on the device.
+    """
+    doc_count = stats.doc_count if stats else dfield.doc_count
+    spans = np.array([dfield.term_span(t) for t in terms], dtype=np.int64)
+    starts, ends = spans[:, 0], spans[:, 1]
+    postings = ends - starts
+    weights = np.zeros(len(terms), dtype=np.float64)
+    bounds = np.zeros(len(terms), dtype=np.float64)
+    for j, term in enumerate(terms):
+        df = _scope_df(dfield, term, stats)
+        if df <= 0 or doc_count <= 0 or postings[j] <= 0:
+            continue
+        w = term_weight(df, doc_count, boost, params)
+        tm = float(
+            dfield.tile_max[starts[j] // TILE : (ends[j] - 1) // TILE + 1].max()
+        )
+        weights[j] = w
+        bounds[j] = w - w / (1.0 + tm)
+    order = [j for j in np.argsort(bounds, kind="stable") if postings[j] > 0]
+    if len(order) < 2:
+        return None
+    ub_max = float(bounds[order[-1]])
+    non_ess: list[int] = []
+    ub_n = 0.0
+    for j in order[: min(len(order) - 1, MAXSCORE_NPAD)]:
+        if postings[j] <= threshold or 3.0 * (ub_n + bounds[j]) > ub_max:
+            break
+        non_ess.append(int(j))
+        ub_n += float(bounds[j])
+    if not non_ess:
+        return None
+
+    ess = np.array(
+        [j for j in range(len(terms)) if j not in non_ess], dtype=np.int64
+    )
+    first = starts[ess] // TILE
+    n_tiles = np.where(
+        postings[ess] > 0, (ends[ess] - 1) // TILE - first + 1, 0
+    )
+    n_ent = int(n_tiles.sum())
+    nt = _pow2(n_ent, MAXSCORE_NT_FLOOR)
+    if nt > MAXSCORE_NT_MAX:
+        return None
+    owner = np.repeat(np.arange(len(ess)), n_tiles)  # entry -> ess slot
+    within = np.arange(n_ent) - np.repeat(np.cumsum(n_tiles) - n_tiles, n_tiles)
+    tile_ids = np.full(nt, dfield.pad_tile, dtype=np.int32)
+    tile_ids[:n_ent] = first[owner] + within
+    entry = {
+        "starts": starts[ess],
+        "ends": ends[ess],
+        "weights": weights[ess],
+        "term_of": ess,
+    }
+    arrays: dict[str, Any] = {"tile_ids": tile_ids}
+    for key, per_term in entry.items():
+        dtype = np.float32 if key == "weights" else np.int32
+        plane = np.zeros(nt, dtype=dtype)
+        plane[:n_ent] = per_term[owner]
+        arrays[key] = plane
+    n_term = np.full(MAXSCORE_NPAD, MAXSCORE_TPAD, dtype=np.int32)  # no term
+    n_starts = np.zeros(MAXSCORE_NPAD, dtype=np.int32)
+    n_ends = np.zeros(MAXSCORE_NPAD, dtype=np.int32)
+    n_weights = np.zeros(MAXSCORE_NPAD, dtype=np.float32)
+    n_term[: len(non_ess)] = non_ess
+    n_starts[: len(non_ess)] = starts[non_ess]
+    n_ends[: len(non_ess)] = ends[non_ess]
+    n_weights[: len(non_ess)] = weights[non_ess]
+    arrays.update(
+        n_term=n_term,
+        n_starts=n_starts,
+        n_ends=n_ends,
+        n_weights=n_weights,
+        ub_n=np.float32(ub_n),
+        total_floor=np.int32(postings.max()),
+    )
+    spec = (
+        "terms_maxscore", dfield.name, nt, MAXSCORE_TPAD, span_rows_bucket(dfield)
+    )
     return spec, arrays
 
 
@@ -1462,15 +1609,81 @@ class Compiler:
             "boost": np.float32(q.boost),
         }
 
+    def compile_maxscore(
+        self, query: Query, threshold: int
+    ) -> CompiledQuery | None:
+        """Lower MaxScore traffic for a plain score-sorted top-k whose total
+        is clamped at `threshold` (the request's integer track_total_hits;
+        the caller vouches for the request's shape), or None.
+
+        MaxScore traffic is a `match` disjunction of 2 to MAXSCORE_TPAD
+        terms, at least one of whose spans in this segment passes
+        `threshold`, on a field whose precomputed impacts apply. Anything
+        else is None, and its plain plan (`compile`) is unchanged. For
+        MaxScore traffic the MaxScore plan (see _maxscore_arrays), or,
+        where the split declines, `compile_exhaustive`'s plan."""
+        found = self._disjunction_terms(query)
+        if found is None:
+            return None
+        dfield, terms = found
+        stats = self.stats.get(query.field_name)
+        avgdl = stats.avgdl if stats else dfield.avgdl
+        if (
+            dfield.tile_max is None
+            or query.boost <= 0
+            or float(avgdl) != dfield.tn_avgdl
+            or self.params.k1 != dfield.tn_k1
+            or self.params.b != dfield.tn_b
+            or max(e - s for s, e in map(dfield.term_span, terms)) <= threshold
+        ):
+            return None
+        lowered = _maxscore_arrays(
+            dfield, terms, query.boost, self.params, stats, threshold
+        )
+        if lowered is None:
+            return self.compile_exhaustive(query)
+        return CompiledQuery(*lowered)
+
+    def compile_exhaustive(self, query: Query) -> CompiledQuery:
+        """The exhaustive plan of MaxScore traffic the MaxScore kernel does
+        not answer: where the split declines, or where the kernel's
+        threshold check fails. `_terms_arrays`, with the term count padded
+        to MAXSCORE_TPAD and the worklist to at least the segment's
+        span_rows_bucket, so these few queries share one or two compiled
+        shapes instead of one per (worklist, term count) bucket."""
+        dfield, terms = self._disjunction_terms(query)
+        return CompiledQuery(*_terms_arrays(
+            dfield, terms, query.boost, self.params,
+            self.stats.get(query.field_name), True,
+            max(self.nt_floor, span_rows_bucket(dfield)),
+            tpad_floor=MAXSCORE_TPAD,
+        ))
+
+    def _disjunction_terms(self, query: Query):
+        """(field, analyzed terms) of a `match` disjunction of 2 to
+        MAXSCORE_TPAD terms on a present field, else None."""
+        if (
+            not isinstance(query, MatchQuery)
+            or query.operator == "and"
+            or query.minimum_should_match > 1
+        ):
+            return None
+        dfield = self._field_or_none(query.field_name)
+        terms = self._match_analyzer(query).analyze(query.query)
+        if dfield is None or not 2 <= len(terms) <= MAXSCORE_TPAD:
+            return None
+        return dfield, terms
+
+    def _match_analyzer(self, q: MatchQuery):
+        if q.analyzer:
+            return self.mappings.analysis.get(q.analyzer)
+        return self.mappings.analyzer_for(q.field_name, search=True)
+
     def _match(self, q: MatchQuery, scoring: bool) -> tuple[tuple, Any]:
         dfield = self._field_or_none(q.field_name)
         if dfield is None:
             return ("match_none",), {}
-        if q.analyzer:
-            analyzer = self.mappings.analysis.get(q.analyzer)
-        else:
-            analyzer = self.mappings.analyzer_for(q.field_name, search=True)
-        terms = analyzer.analyze(q.query)
+        terms = self._match_analyzer(q).analyze(q.query)
         if not terms:
             return ("match_none",), {}
         stats = self.stats.get(q.field_name)

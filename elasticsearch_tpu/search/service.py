@@ -1062,9 +1062,7 @@ class SearchService:
             for i in sorted(alive):
                 with TRACER.span("query.compile"):
                     try:
-                        compiled[i] = self.engine.compiler_for(
-                            handle, stats
-                        ).compile(requests[i].query)
+                        compiled[i] = self._lower(handle, stats, requests[i])
                     except ValueError as e:
                         errors[i] = e
                         alive.discard(i)
@@ -1225,9 +1223,10 @@ class SearchService:
                     except Exception:
                         # Same contract as the single-request path: an
                         # oracle gap falls back to the device (for every
-                        # lane not yet served) instead of failing the whole
-                        # batch, and the penalty observation stops the
-                        # planner from retrying the oracle for this class.
+                        # lane not yet served, below) instead of failing
+                        # the whole batch, and the penalty observation
+                        # stops the planner from retrying the oracle for
+                        # this class.
                         if plan_class is not None:
                             # observe (not record): a failed attempt is a
                             # cost sample, not a served decision.
@@ -1235,36 +1234,39 @@ class SearchService:
                                 plan_class, "oracle",
                                 self.FAILED_BACKEND_PENALTY_S,
                             )
-                        self._device_batch(
-                            handle, spec, remaining, compiled, ks, k_max,
-                            plan_class, cands, totals, seg_tree=seg_tree,
-                            masks=masks,
-                        )
-                        return
+                        break
                     remaining.pop(0)
                     self._append_plain(
                         cands[i], handle, scores, ids,
                         min(ks[i], len(ids)),
                     )
                     totals[i] += int(tot)
+                    if bm25_device.supports_maxscore(spec):
+                        self._count_maxscore("oracle")
                     if plan_class is not None:
                         self.planner.record(
                             plan_class, "oracle",
                             time.monotonic() - lane_t0,
                         )
-        else:
-            self._device_batch(
+            rows = remaining
+        if rows:
+            redo = self._device_batch(
                 handle, spec, rows, compiled, ks, k_max, plan_class, cands,
                 totals, seg_tree=seg_tree, masks=masks,
+            )
+            self._maxscore_fallback(
+                handle, stats, requests, redo, compiled, ks, cands, totals,
+                seg_tree,
             )
 
     def _device_batch(
         self, handle, spec, rows, compiled, ks, k_max, plan_class, cands,
         totals, seg_tree=None, masks=None,
-    ) -> None:
+    ) -> list[int]:
         """One padded device launch for a same-spec row group. Filter-
         cache planes (`masks`) ride the seg tree — one shared plane per
-        launch, never stacked per lane."""
+        launch, never stacked per lane. Returns the MaxScore lanes whose
+        threshold check failed, unanswered (`_maxscore_fallback`)."""
         import jax
 
         t0 = time.monotonic()
@@ -1287,18 +1289,20 @@ class SearchService:
                     cands[i], handle, s, idx, min(ks[i], tot, len(idx))
                 )
                 totals[i] += tot
-            return
+            return []
         with TRACER.span("device.stage"):
             arrays_b = jax.tree.map(
                 lambda *xs: np.stack(xs), *[compiled[i].arrays for i in rows]
             )
             if self.device is not None:
                 self.device.h2d(arrays_b)
-        kernel = (
-            bm25_device.execute_batch_sparse
-            if bm25_device.supports_sparse(spec)
-            else bm25_device.execute_batch
-        )
+        maxscore = bm25_device.supports_maxscore(spec)
+        if maxscore:
+            kernel = bm25_device.execute_batch_maxscore
+        elif bm25_device.supports_sparse(spec):
+            kernel = bm25_device.execute_batch_sparse
+        else:
+            kernel = bm25_device.execute_batch
         kind = str(spec[0]) if isinstance(spec, tuple) and spec else "dense"
         # Per-launch timing wrapper (obs/metrics.DeviceInstruments.timed):
         # queue/execute split around block_until_ready + retrace-census
@@ -1314,20 +1318,51 @@ class SearchService:
             with TRACER.span("device.dispatch"):
                 out = kernel(seg_tree, spec, arrays_b, k_max)
             out = tl.dispatched(out)
+        redo: list[int] = []
         with TRACER.span("device.fetch"):
-            s_b, i_b, t_b = jax.device_get(out)
+            s_b, i_b, t_b, *ok_b = jax.device_get(out)
             elapsed = time.monotonic() - t0
             for row, i in enumerate(rows):
-                tot = int(t_b[row])
-                nn = min(ks[i], tot, s_b.shape[1])
-                self._append_plain(cands[i], handle, s_b[row], i_b[row], nn)
-                totals[i] += tot
-                if plan_class is not None:
+                if plan_class is not None and (tl.first or tl.compiles):
+                    # A launch that traced and compiled (or loaded) its
+                    # program is no sample of the class's cost: priced by
+                    # it, the class would stay on the oracle, never to be
+                    # observed on the device again. Counted, not folded.
+                    self.planner.note("device_batched")
+                elif plan_class is not None:
                     # Amortized per-lane cost: what this class actually
                     # pays per query when batched.
                     self.planner.record(
                         plan_class, "device_batched", elapsed / len(rows)
                     )
+                if ok_b and not ok_b[0][row]:
+                    redo.append(i)
+                    continue
+                tot = int(t_b[row])
+                nn = min(ks[i], tot, s_b.shape[1])
+                self._append_plain(cands[i], handle, s_b[row], i_b[row], nn)
+                totals[i] += tot
+        if maxscore:
+            self._count_maxscore("served", len(rows) - len(redo))
+            self._count_maxscore("fallback", len(redo))
+        return redo
+
+    def _maxscore_fallback(
+        self, handle, stats, requests, rows, compiled, ks, cands, totals,
+        seg_tree,
+    ) -> None:
+        """Answer MaxScore lanes whose threshold check failed from their
+        exhaustive plans (`_exhaustive_plan`), one launch per spec."""
+        groups: dict[tuple, list[int]] = {}
+        for i in rows:
+            compiled[i] = self._exhaustive_plan(handle, stats, requests[i])
+            groups.setdefault(compiled[i].spec, []).append(i)
+        for spec, group in groups.items():
+            self._device_batch(
+                handle, spec, group, compiled, ks,
+                max(ks[i] for i in group), None, cands, totals,
+                seg_tree=seg_tree,
+            )
 
     @staticmethod
     def _append_plain(bucket, handle, scores, ids, n) -> None:
@@ -1418,6 +1453,60 @@ class SearchService:
         else:
             live = handle.live_host
         return None if live.all() else live
+
+    def _maxscore_threshold(self, request, handle) -> int | None:
+        """The track_total_hits threshold under which this request's query
+        may take the MaxScore lowering on this segment, or None.
+
+        Only a plain score-sorted top-k (no sort, cursor, rescore, aggs or
+        knn, k > 0) with an integer threshold qualifies: its answer is the
+        top k plus a total clamped at the threshold, which the MaxScore
+        kernel gives exactly (ops/bm25_device.execute_maxscore). A segment
+        with deleted docs does not: the kernel's total floor counts a
+        term's whole span as live matches."""
+        tth = request.track_total_hits
+        if (
+            isinstance(tth, bool)
+            or request.sort is not None
+            or request.search_after is not None
+            or request.rescore
+            or request.aggs is not None
+            or request.knn is not None
+            or max(0, request.from_) + max(0, request.size) <= 0
+            or self._host_live(handle) is not None
+        ):
+            return None
+        return int(tth)
+
+    def _lower(self, handle, stats, request):
+        """The request's query compiled for one segment: through
+        Compiler.compile_maxscore where `_maxscore_threshold` allows it,
+        else as always. A scored disjunction of two or more terms whose
+        plan is not the MaxScore one counts as the MaxScore counter's
+        `bypass`."""
+        compiler = self.engine.compiler_for(handle, stats)
+        threshold = self._maxscore_threshold(request, handle)
+        compiled = None
+        if threshold is not None:
+            compiled = compiler.compile_maxscore(request.query, threshold)
+        if compiled is None:
+            compiled = compiler.compile(request.query)
+        spec = compiled.spec
+        if spec[0] in ("terms", "terms_gather") and spec[3] >= 2:
+            self._count_maxscore("bypass")
+        return compiled
+
+    def _exhaustive_plan(self, handle, stats, request):
+        """The exhaustive plan of a MaxScore-lowered request whose
+        threshold check failed (Compiler.compile_exhaustive)."""
+        with TRACER.span("query.compile"):
+            return self.engine.compiler_for(handle, stats).compile_exhaustive(
+                request.query
+            )
+
+    def _count_maxscore(self, outcome: str, n: int = 1) -> None:
+        if self.device is not None and n:
+            self.device.maxscore(outcome, n)
 
     def _decide_backend(
         self,
@@ -1814,8 +1903,7 @@ class SearchService:
         with TRACER.span("device.stage"):
             seg_tree = bm25_device.segment_tree(handle.device)
         with TRACER.span("query.compile"):
-            compiler = self.engine.compiler_for(handle, stats)
-            compiled = compiler.compile(request.query)
+            compiled = self._lower(handle, stats, request)
             # Filter cache: swap cacheable filter-context clauses for
             # their cached (or freshly admitted) mask planes —
             # bit-identical by construction, the plane IS the clause's
@@ -1965,6 +2053,10 @@ class SearchService:
                                 plan_class, "oracle",
                                 self.FAILED_BACKEND_PENALTY_S,
                             )
+                if backend == "oracle" and bm25_device.supports_maxscore(
+                    compiled.spec
+                ):
+                    self._count_maxscore("oracle")
                 if backend == "blockmax":
                     s, i, t, _rel = bm25_device.execute_batch_blockmax(
                         seg_tree, compiled.spec, [compiled.arrays], k,
@@ -1977,6 +2069,25 @@ class SearchService:
                         instruments=self.device,
                     )
                     scores, ids, tot = s[0], i[0], int(t[0])
+                elif backend == "device" and bm25_device.supports_maxscore(
+                    compiled.spec
+                ):
+                    with TRACER.span("device.dispatch"):
+                        scores, ids, tot, ok = bm25_device.execute_maxscore(
+                            seg_tree, compiled.spec, compiled.arrays, k
+                        )
+                    with TRACER.span("device.fetch"):
+                        scores, ids = np.asarray(scores), np.asarray(ids)
+                        ok = bool(ok)
+                    self._count_maxscore("served" if ok else "fallback")
+                    if not ok:
+                        plan = self._exhaustive_plan(handle, stats, request)
+                        with TRACER.span("device.dispatch"):
+                            scores, ids, tot = bm25_device.execute_auto(
+                                seg_tree, plan.spec, plan.arrays, k
+                            )
+                        with TRACER.span("device.fetch"):
+                            scores, ids = np.asarray(scores), np.asarray(ids)
                 elif backend in ("device", "cached_mask"):
                     with TRACER.span("device.dispatch"):
                         scores, ids, tot = bm25_device.execute_auto(

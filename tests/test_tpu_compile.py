@@ -71,11 +71,17 @@ def _compile_single(one_chip, fn, seg_tree, compiled, *static):
     return out
 
 
-def test_sparse_disjunction_at_1m_docs(one_chip):
+@pytest.fixture(scope="module")
+def million():
+    """1M Zipf docs, packed."""
+    mappings, segment = build_zipf_segment(1_000_000, vocab_size=30_000)
+    return mappings, segment, pack_segment(segment)
+
+
+def test_sparse_disjunction_at_1m_docs(one_chip, million):
     """cfg2's shape: a 4-term `match` disjunction over 1M Zipf docs, whose
     head term reaches the widest worklist bucket the smoke serves."""
-    mappings, segment = build_zipf_segment(1_000_000, vocab_size=30_000)
-    dev = pack_segment(segment)
+    mappings, segment, dev = million
     terms = pick_query_terms(segment, np.random.default_rng(1), 1, 4)[0]
     compiled = Compiler(dev.fields, dev.doc_values, mappings).compile(
         parse_query({"match": {"body": " ".join(terms)}})
@@ -86,6 +92,28 @@ def test_sparse_disjunction_at_1m_docs(one_chip):
         one_chip, bm25_device.execute_sparse,
         bm25_device.segment_tree(dev), compiled, 10,
     )
+
+
+def test_maxscore_disjunction_at_1m_docs(one_chip, million):
+    """A stop word and three rare terms under the default track_total_hits:
+    the MaxScore program reads the stop word's span in place, so its
+    scratch memory stays well below one copy of the postings plane."""
+    mappings, segment, dev = million
+    fld = segment.fields["body"]
+    by_df = sorted(fld.terms, key=lambda t: -fld.df[fld.terms[t]])
+    compiled = Compiler(dev.fields, dev.doc_values, mappings).compile_maxscore(
+        parse_query({"match": {"body": " ".join(
+            [by_df[0], by_df[2000], by_df[9000], by_df[20000]]
+        )}}),
+        10_000,
+    )
+    assert compiled.spec[0] == "terms_maxscore"
+    out = _compile_single(
+        one_chip, bm25_device.execute_maxscore,
+        bm25_device.segment_tree(dev), compiled, 10,
+    )
+    plane = dev.fields["body"].doc_ids
+    assert out.memory_analysis().temp_size_in_bytes < plane.nbytes / 2
 
 
 @pytest.fixture(scope="module")
