@@ -246,10 +246,18 @@ def open_session(name: str, *, spec: dict | None = None,
         log(f"snapshot repository: {'from the cache' if hit else 'built by the program and cached'}")
         t = time.monotonic()
         load.restore(server.call, repo)
-        count = server.call("GET", f"/{gen_queries.INDEX}/_count")["count"]
-        if count != corpus.n_docs:
-            raise RuntimeError(f"_count {count} after restoring {corpus.n_docs} passages")
+        # Each shard holds what the program's `_id` routing puts there, as
+        # a client reads it, or the run measures a layout no user has.
+        want = load.shard_counts(corpus.n_docs, load.n_shards(settings))
+        rows = sorted((int(r["shard"]), int(r["docs"]))
+                      for r in server.call("GET", "/_cat/shards?format=json")
+                      if r["index"] == gen_queries.INDEX and r["prirep"] == "p")
+        have = [docs for _, docs in rows]
+        if have != want:
+            raise RuntimeError(f"docs per shard {have} after the restore; the routing of "
+                               f"{corpus.n_docs} passages wants {want}")
         split["restore_s"] = time.monotonic() - t
+        log(f"index: docs per shard {have} (_cat/shards), as the routing wants")
     except BaseException:
         server.close()
         raise

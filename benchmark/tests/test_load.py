@@ -1,85 +1,156 @@
 """The snapshot repository the benchmark keeps holds what `_bulk` +
-`_refresh` + `_forcemerge` would have built from the same passages, and a
-run restores it from the cache the first run left."""
+`_refresh` + `_forcemerge` would have built from the same passages, shard
+by shard as the program routes each `_id`; a run restores it from the
+cache the first run left, and refuses an index laid out otherwise."""
 
 import copy
 import glob
+import hashlib
 import json
 import os
-import tempfile
-import threading
 
 import numpy as np
+import pytest
 
-from benchmark import load
+from benchmark import load, run
 from benchmark.gen import corpus as C
 from benchmark.gen.queries import INDEX
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CONFIG = os.path.join(HERE, "..", "configs", "msmarco-passage-1of4.json")
+# `_digest` of the repository the loader wrote at 400 passages in one
+# shard before it routed passages to shards: one shard must stay as it was.
+ONE_SHARD_400 = "62111b098a275e6374f606b20b1a9ac4165f0eb9726401023e82ed8baae0410a"
 
 
-def _blob(repo):
-    [npz] = glob.glob(os.path.join(repo, "blobs", "*", "seg-1.npz"))
-    meta = json.load(open(npz[: -len(".npz")] + ".meta.json"))
-    return dict(np.load(npz)), meta
-
-
-def test_snapshot_blob_equals_a_bulk_built_segment():
-    from elasticsearch_tpu.index import store
-    from elasticsearch_tpu.rest.server import create_server
-
+def _config(docs: int, shards: int = 1) -> dict:
     cfg = copy.deepcopy(json.load(open(CONFIG)))
-    cfg["corpus"]["docs"] = 400
+    cfg["corpus"]["docs"] = docs
+    cfg["index"]["settings"]["index"]["number_of_shards"] = shards
+    return cfg
+
+
+def _manifest_shards(repo: str) -> list[dict]:
+    [path] = glob.glob(os.path.join(repo, "snapshots", "*.json"))
+    return json.load(open(path))["indices"][INDEX]["shards"]
+
+
+def _blob(repo: str, blob: str):
+    base = os.path.join(repo, "blobs", blob, "seg-1")
+    return (dict(np.load(base + ".npz")), json.load(open(base + ".meta.json")),
+            open(base + ".src.jsonl").read())
+
+
+def _digest(repo: str) -> str:
+    """sha256 of every blob and live file, by path, and the manifest's
+    shard entries (the rest of the manifest holds a uuid and times)."""
+    h = hashlib.sha256()
+    for sub in ("blobs", os.path.join("snapshots", load.SNAPSHOT)):
+        for dp, _, files in sorted(os.walk(os.path.join(repo, sub))):
+            for f in sorted(files):
+                p = os.path.join(dp, f)
+                h.update(os.path.relpath(p, repo).encode() + b"\0")
+                with open(p, "rb") as fh:
+                    h.update(fh.read())
+    h.update(json.dumps(_manifest_shards(repo), sort_keys=True).encode())
+    return h.hexdigest()
+
+
+def _bulk(node, corpus) -> None:
+    lines = []
+    for d in range(corpus.n_docs):
+        toks = corpus.tokens[corpus.offsets[d]:corpus.offsets[d + 1]]
+        lines.append(json.dumps({"index": {"_id": str(d)}}))
+        lines.append(json.dumps({"body": " ".join(C.term_name(int(t)) for t in toks),
+                                 "tag": corpus.tag_values[corpus.tags[d]]}))
+    for lo in range(0, len(lines), 200):
+        resp = node.bulk("\n".join(lines[lo:lo + 200]) + "\n", default_index=INDEX)
+        assert not resp["errors"]
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_snapshot_blob_equals_a_bulk_built_segment(tmp_path, shards):
+    cfg = _config(400, shards)
+    settings = cfg["index"]["settings"]
     corpus = C.generate(cfg)
-    http, rest = create_server(port=0)
-    th = threading.Thread(target=http.serve_forever, daemon=True)
-    th.start()
+    bulk_repo, my_repo = str(tmp_path / "bulk"), str(tmp_path / "mine")
+    s = run.Server()
     try:
-        node = rest.node
-        node.create_index(INDEX, {"settings": cfg["index"]["settings"], "mappings": load.mappings()})
-        lines = []
-        for d in range(corpus.n_docs):
-            toks = corpus.tokens[corpus.offsets[d]:corpus.offsets[d + 1]]
-            lines.append(json.dumps({"index": {"_id": str(d)}}))
-            lines.append(json.dumps({"body": " ".join(C.term_name(int(t)) for t in toks),
-                                     "tag": corpus.tag_values[corpus.tags[d]]}))
-        for lo in range(0, len(lines), 200):
-            resp = node.bulk("\n".join(lines[lo:lo + 200]) + "\n", default_index=INDEX)
-            assert not resp["errors"]
+        node = s.rest.node
+        node.create_index(INDEX, {"settings": settings, "mappings": load.mappings()})
+        _bulk(node, corpus)
         node.refresh(INDEX)
         node.force_merge(INDEX, max_num_segments=1)
-        repo = tempfile.mkdtemp()
-        node.put_repository("r", {"type": "fs", "settings": {"location": repo}})
+        node.put_repository("r", {"type": "fs", "settings": {"location": bulk_repo}})
         node.create_snapshot("r", "s", {"indices": INDEX})
-        bulk_arrays, bulk_meta = _blob(repo)
-        [src] = glob.glob(os.path.join(repo, "blobs", "*", "seg-1.src.jsonl"))
-        bulk_src = open(src).read()
+        s.call("DELETE", f"/{INDEX}")
+        assert load.ensure_repo(s.call, corpus, settings, my_repo) is False
     finally:
-        http.shutdown()
-        http.server_close()
-        rest.close()
-    mine = tempfile.mkdtemp()
-    store.persist_segment(mine, 1, load.build_segment(corpus))
-    my_arrays = dict(np.load(os.path.join(mine, "seg-1.npz")))
-    my_meta = json.load(open(os.path.join(mine, "seg-1.meta.json")))
-    assert open(os.path.join(mine, "seg-1.src.jsonl")).read() == bulk_src
-    assert sorted(my_arrays) == sorted(bulk_arrays)
-    differ = [k for k in my_arrays if my_arrays[k].dtype != bulk_arrays[k].dtype
-              or not np.array_equal(my_arrays[k], bulk_arrays[k])]
-    assert not differ
-    assert my_meta == bulk_meta
+        s.close()
+    bulk_shards, my_shards = _manifest_shards(bulk_repo), _manifest_shards(my_repo)
+    assert len(bulk_shards) == len(my_shards) == shards
+    for bulk, mine in zip(bulk_shards, my_shards):
+        assert mine["max_seqno"] == bulk["max_seqno"]
+        [bulk_seg], [my_seg] = bulk["segments"], mine["segments"]
+        b_arrays, b_meta, b_src = _blob(bulk_repo, bulk_seg["blob"])
+        m_arrays, m_meta, m_src = _blob(my_repo, my_seg["blob"])
+        assert m_src == b_src and m_meta == b_meta
+        assert sorted(m_arrays) == sorted(b_arrays)
+        differ = [k for k in m_arrays if m_arrays[k].dtype != b_arrays[k].dtype
+                  or not np.array_equal(m_arrays[k], b_arrays[k])]
+        assert not differ
+        b_live = np.load(os.path.join(bulk_repo, "snapshots", "s", bulk_seg["live"]))
+        m_live = np.load(os.path.join(my_repo, "snapshots", load.SNAPSHOT, my_seg["live"]))
+        assert np.array_equal(m_live, b_live)
+    assert sum(mine["max_seqno"] + 1 for mine in my_shards) == corpus.n_docs
 
 
-def _server():
-    from benchmark.run import Server
+def test_one_shard_writes_what_it_wrote_before_routing(tmp_path):
+    cfg = _config(400)
+    corpus = C.generate(cfg)
+    s = run.Server()
+    try:
+        load.ensure_repo(s.call, corpus, cfg["index"]["settings"], str(tmp_path / "repo"))
+    finally:
+        s.close()
+    assert _digest(str(tmp_path / "repo")) == ONE_SHARD_400
 
-    return Server()
+
+def test_shard_of_is_the_programs_routing():
+    from elasticsearch_tpu.parallel.routing import shard_for_id
+
+    for shards in (1, 2, 3, 4, 8):
+        of = load.shard_of(20000, shards)
+        assert [int(x) for x in of] == [shard_for_id(str(d), shards) for d in range(20000)]
+    big = load.shard_of(8_841_823, 4)
+    sample = np.random.default_rng(7).integers(0, 8_841_823, 2000)
+    assert all(big[d] == shard_for_id(str(int(d)), 4) for d in sample)
+    assert load.shard_counts(400, 4) == [94, 81, 117, 108]
 
 
-def test_repository_is_built_once_and_restored_from_the_cache(tmp_path):
-    cfg = copy.deepcopy(json.load(open(CONFIG)))
-    cfg["corpus"]["docs"] = 300
+def test_four_shards_restore_with_each_passage_where_its_id_routes(tmp_path):
+    cfg = _config(400, 4)
+    corpus = C.generate(cfg)
+    repo = str(tmp_path / "repo")
+    s = run.Server()
+    try:
+        load.ensure_repo(s.call, corpus, cfg["index"]["settings"], repo)
+        load.restore(s.call, repo)
+        docs = {int(r["shard"]): int(r["docs"]) for r in s.call("GET", "/_cat/shards?format=json")
+                if r["index"] == INDEX}
+        assert [docs[i] for i in range(4)] == load.shard_counts(400, 4) == [94, 81, 117, 108]
+        for d in np.random.default_rng(3).choice(400, 40, replace=False).tolist():
+            got = s.call("GET", f"/{INDEX}/_doc/{d}")
+            toks = corpus.tokens[corpus.offsets[d]:corpus.offsets[d + 1]]
+            assert got["found"], d
+            assert got["_source"]["body"] == " ".join(C.term_name(int(t)) for t in toks)
+    finally:
+        s.close()
+
+
+@pytest.mark.parametrize("shards", [1, 4])
+def test_repository_is_built_once_and_restored_from_the_cache(tmp_path, shards):
+    cfg = _config(300, shards)
     settings = cfg["index"]["settings"]
     c1, hit1 = C.load(cfg, str(tmp_path))
     c2, hit2 = C.load(cfg, str(tmp_path))
@@ -88,7 +159,7 @@ def test_repository_is_built_once_and_restored_from_the_cache(tmp_path):
     repo = str(tmp_path / "repo")
     answers = []
     for want_hit in (False, True):
-        s = _server()
+        s = run.Server()
         try:
             assert load.ensure_repo(s.call, c1, settings, repo) is want_hit
             load.restore(s.call, repo)
@@ -99,3 +170,25 @@ def test_repository_is_built_once_and_restored_from_the_cache(tmp_path):
             s.close()
     hits = [[(h["_id"], h["_score"]) for h in a["hits"]["hits"]] for a in answers]
     assert hits[0] == hits[1] and len(hits[0]) == 10
+
+
+def test_open_session_refuses_a_layout_the_routing_does_not_make(tmp_path, monkeypatch):
+    """Every passage in shard 0 of a four-shard index, as the loader laid
+    a repository out before it routed passages: `_count` is right, and
+    the run still refuses before any traffic."""
+    spec = run.load_spec()
+    cell = spec["workloads"][0]["name"]
+    cfg = _config(2000, 4)
+
+    def all_in_shard_0(corpus, shards):
+        [whole] = load.split(corpus, 1)
+        empty = load.Shard(whole.ids[:0], whole.tokens[:0], whole.lengths[:0], whole.tags[:0],
+                           whole.vocab, whole.tag_values)
+        return [whole] + [empty] * (shards - 1)
+
+    monkeypatch.setattr(run, "CACHE_ROOT", str(tmp_path))
+    real_split = load.split
+    monkeypatch.setattr(load, "split", lambda corpus, shards: (
+        all_in_shard_0(corpus, shards) if shards > 1 else real_split(corpus, shards)))
+    with pytest.raises(RuntimeError, match=r"docs per shard \[2000, 0, 0, 0\]"):
+        run.open_session(cell, spec=spec, config=cfg, require_tpu=False)
