@@ -1050,12 +1050,34 @@ class Engine:
         )
         seg_idx = len(self.segments)
         self.segments.append(handle)
-        for local, doc_id in enumerate(segment.ids):
-            if live[local]:
-                self._live_ids[doc_id] = (seg_idx, local)
-                self._versions[doc_id] = segment.doc_version(local)
-                self._doc_seqnos[doc_id] = segment.doc_seqno(local)
-            self._bump_auto_id(doc_id)
+        # The id/version/seqno maps of the live docs, in local order (a
+        # later copy of an id wins, as it would doc by doc): bulk dict
+        # updates, since a restored shard holds millions of docs.
+        n = segment.num_docs
+        ids = segment.ids
+        versions = (
+            segment.versions.tolist() if segment.versions is not None
+            else [1] * n
+        )
+        seqnos = (
+            segment.seqnos.tolist() if segment.seqnos is not None
+            else [-1] * n
+        )
+        local_ids = range(n)
+        if not live.all():
+            keep = live.tolist()
+            ids, versions, seqnos, local_ids = (
+                list(itertools.compress(col, keep))
+                for col in (ids, versions, seqnos, local_ids)
+            )
+        self._live_ids.update(
+            zip(ids, zip(itertools.repeat(seg_idx), local_ids))
+        )
+        self._versions.update(zip(ids, versions))
+        self._doc_seqnos.update(zip(ids, seqnos))
+        for doc_id in segment.ids:
+            if doc_id.startswith("_auto_"):
+                self._bump_auto_id(doc_id)
         if segment.seqnos is not None and len(segment.seqnos):
             self._seqno = max(self._seqno, int(segment.seqnos.max()))
         self._stats_cache = None

@@ -42,6 +42,7 @@ from dataclasses import replace as dc_replace
 import numpy as np
 
 from .segment import FieldIndex, NestedBlock, Segment
+from .store import concat_sources, take_sources
 
 
 def _csr_term_of(fi: FieldIndex) -> np.ndarray:
@@ -233,7 +234,7 @@ def compact_segment(segment: Segment, live: np.ndarray) -> Segment:
         fields=fields,
         doc_values=doc_values,
         vectors=vectors,
-        sources=[segment.sources[int(i)] for i in keep_idx],
+        sources=take_sources(segment.sources, keep_idx),
         ids=[segment.ids[int(i)] for i in keep_idx],
         versions=versions,
         seqnos=seqnos,
@@ -460,10 +461,9 @@ def concat_segments(segments: list[Segment]) -> Segment:
             for doc, query in seg.percolator.get(name, ()):
                 entries.append((doc + bases[m], query))
         percolator[name] = entries
-    sources: list = []
+    sources = concat_sources([seg.sources for seg in segments])
     ids: list[str] = []
     for seg in segments:
-        sources.extend(seg.sources)
         ids.extend(seg.ids)
     return Segment(
         num_docs=n_total,

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import json
 import os
+from collections.abc import Sequence
 from typing import Any
 
 import numpy as np
@@ -174,6 +175,107 @@ def _segment_from(
     )
 
 
+def _run_len(run) -> int:
+    return len(run) if isinstance(run, list) else len(run[1])
+
+
+class StoredSources(Sequence):
+    """A segment's `_source` per local doc once the segment was loaded
+    from disk: the stored JSON lines, decoded when a doc's source is read
+    (fetch, GET, reindex), so loading a shard parses none of them — most
+    of the host time of a restore or recovery. Each read of a stored line
+    returns a fresh object. Compaction (`take_sources`) and concatenation
+    with other segments (`concat_sources`) keep the lines undecoded; a
+    concatenated in-memory segment's sources ride along as a list run."""
+
+    def __init__(self, runs: list):
+        # Each run: (blob, line starts, line ends), or a list of sources.
+        self._runs = [r for r in runs if _run_len(r)]
+        self._bounds = np.cumsum([0] + [_run_len(r) for r in self._runs])
+
+    @classmethod
+    def from_lines(cls, blob: bytes) -> "StoredSources":
+        ends = np.flatnonzero(np.frombuffer(blob, dtype=np.uint8) == 10) + 1
+        starts = np.concatenate([[0], ends[:-1]]) if len(ends) else ends
+        return cls([(blob, starts.astype(np.int64), ends.astype(np.int64))])
+
+    def __len__(self) -> int:
+        return int(self._bounds[-1])
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [self[j] for j in range(*i.indices(len(self)))]
+        n = len(self)
+        if i < 0:
+            i += n
+        if not 0 <= i < n:
+            raise IndexError("source index out of range")
+        r = int(np.searchsorted(self._bounds, i, side="right")) - 1
+        run, j = self._runs[r], i - int(self._bounds[r])
+        if isinstance(run, list):
+            return run[j]
+        blob, starts, ends = run
+        return json.loads(blob[starts[j]:ends[j]])
+
+    def __iter__(self):
+        for run in self._runs:
+            if isinstance(run, list):
+                yield from run
+            else:
+                blob, starts, ends = run
+                for a, b in zip(starts.tolist(), ends.tolist()):
+                    yield json.loads(blob[a:b])
+
+    def __eq__(self, other) -> bool:
+        if not isinstance(other, Sequence) or isinstance(other, (str, bytes)):
+            return NotImplemented
+        return len(self) == len(other) and all(a == b for a, b in zip(self, other))
+
+    def _take(self, idx: np.ndarray) -> "StoredSources":
+        runs = []
+        for r, run in enumerate(self._runs):
+            lo, hi = self._bounds[r], self._bounds[r + 1]
+            sel = idx[(idx >= lo) & (idx < hi)] - lo
+            if isinstance(run, list):
+                runs.append([run[j] for j in sel.tolist()])
+            else:
+                blob, starts, ends = run
+                runs.append((blob, starts[sel], ends[sel]))
+        return StoredSources(runs)
+
+    def _lines(self):
+        for run in self._runs:
+            if isinstance(run, list):
+                yield from _json_lines(run)
+            else:
+                blob, starts, ends = run
+                for a, b in zip(starts.tolist(), ends.tolist()):
+                    yield blob[a:b]
+
+
+def _json_lines(sources):
+    for src in sources:
+        yield (json.dumps(src, separators=(",", ":")) + "\n").encode()
+
+
+def take_sources(sources, idx: np.ndarray):
+    """The sources of the docs `idx` (ascending local ids), stored lines
+    left undecoded."""
+    if isinstance(sources, StoredSources):
+        return sources._take(np.asarray(idx, dtype=np.int64))
+    return [sources[int(i)] for i in idx]
+
+
+def concat_sources(seqs: list):
+    """Several segments' sources in order, stored lines left undecoded."""
+    if not any(isinstance(s, StoredSources) for s in seqs):
+        return [src for s in seqs for src in s]
+    runs: list = []
+    for s in seqs:
+        runs.extend(s._runs if isinstance(s, StoredSources) else [list(s)])
+    return StoredSources(runs)
+
+
 def persist_segment(path: str, seg_id: int, segment: Segment) -> None:
     """Write one immutable segment (postings + doc values + sources)."""
     arrays, meta = _segment_arrays(segment)
@@ -182,9 +284,13 @@ def persist_segment(path: str, seg_id: int, segment: Segment) -> None:
         np.savez(f, **arrays)
         f.flush()
         os.fsync(f.fileno())
-    with open(base + ".src.jsonl", "w") as f:
-        for src in segment.sources:
-            f.write(json.dumps(src, separators=(",", ":")) + "\n")
+    sources = segment.sources
+    with open(base + ".src.jsonl", "wb") as f:
+        for line in (
+            sources._lines() if isinstance(sources, StoredSources)
+            else _json_lines(sources)
+        ):
+            f.write(line)
         f.flush()
         os.fsync(f.fileno())
     with open(base + ".meta.json", "w") as f:
@@ -210,10 +316,8 @@ def load_segment(path: str, seg_id: int) -> tuple[Segment, np.ndarray]:
     with open(base + ".meta.json") as f:
         meta = json.load(f)
     data = np.load(base + ".npz")
-    sources = []
-    with open(base + ".src.jsonl") as f:
-        for line in f:
-            sources.append(json.loads(line))
+    with open(base + ".src.jsonl", "rb") as f:
+        sources = StoredSources.from_lines(f.read())
     segment = _segment_from(data, meta, sources=sources)
     live_path = base + ".live.npz"
     if os.path.exists(live_path):

@@ -44,6 +44,14 @@ from .segment import FieldIndex, Segment
 TILE = 256  # postings per tile; multiple of the 128-lane TPU layout
 
 
+def _putter(device, stack_axis: bool):
+    """The upload of one host plane: as it is, or with a leading axis of
+    one (the shard row a mesh assembles its stacked arrays from)."""
+    if stack_axis:
+        return lambda x: jax.device_put(x[None], device)
+    return lambda x: jax.device_put(x, device)
+
+
 def _pad_to_tile(arr: np.ndarray, pad_value, tile: int = TILE) -> np.ndarray:
     """Pad to a tile multiple PLUS one extra all-padding sentinel tile.
 
@@ -129,7 +137,7 @@ class DeviceField:
     @property
     def pos_pad_tile(self) -> int:
         """Tile id of the all-sentinel padding tile of the position planes."""
-        return self.pos_doc.shape[0] - 1
+        return self.pos_doc.shape[-2] - 1
 
     @property
     def num_terms(self) -> int:
@@ -137,12 +145,12 @@ class DeviceField:
 
     @property
     def num_tiles(self) -> int:
-        return self.doc_ids.shape[0]
+        return self.doc_ids.shape[-2]
 
     @property
     def pad_tile(self) -> int:
         """Tile id of the all-sentinel padding tile (always the last)."""
-        return self.doc_ids.shape[0] - 1
+        return self.doc_ids.shape[-2] - 1
 
     @property
     def avgdl(self) -> float:
@@ -229,6 +237,7 @@ def pack_field(
     k1: float = 1.2,
     b: float = 0.75,
     min_pos_tiles: int = 0,
+    stack_axis: bool = False,
 ) -> DeviceField:
     """Pack one FieldIndex into tiled device arrays.
 
@@ -237,7 +246,8 @@ def pack_field(
     `min_tiles` pads the tile axis so shards stack to equal shapes.
     `avgdl` is the statistics scope used for the precomputed impacts —
     shard-level (cross-segment) or global (cross-shard); defaults to this
-    segment's own.
+    segment's own. `stack_axis` gives every device array a leading axis of
+    one, so a mesh stacks the arrays of its shards without a copy.
     """
     if avgdl is None:
         avgdl = field.avgdl
@@ -257,7 +267,7 @@ def pack_field(
     doc_tiles_host = doc_ids.reshape(-1, TILE)
     tile_doc_lo = doc_tiles_host.min(axis=1)
     tile_doc_hi = doc_tiles_host.max(axis=1)
-    put = lambda x: jax.device_put(x, device)
+    put = _putter(device, stack_axis)
     pos_doc = pos_val = None
     pos_offsets_host = None
     if field.positions is not None:
@@ -271,8 +281,8 @@ def pack_field(
             extra = min_pos_tiles * TILE - len(pd)
             pd = np.concatenate([pd, np.full(extra, num_docs, dtype=np.int32)])
             pv = np.concatenate([pv, np.full(extra, -1, dtype=np.int32)])
-        pos_doc = jax.device_put(pd.reshape(-1, TILE), device)
-        pos_val = jax.device_put(pv.reshape(-1, TILE), device)
+        pos_doc = put(pd.reshape(-1, TILE))
+        pos_val = put(pv.reshape(-1, TILE))
         pos_offsets_host = field.pos_offsets
     ord_terms = None
     if not field.has_norms:
@@ -401,6 +411,7 @@ def pack_segment_delta(
     k1: float = 1.2,
     b: float = 0.75,
     field_pos_min_tiles: dict[str, int] | None = None,
+    stack_axis: bool = False,
 ) -> tuple[DeviceSegment, int, int]:
     """pack_segment with per-plane upload skipping against a previous pack.
 
@@ -424,6 +435,7 @@ def pack_segment_delta(
             k1=k1,
             b=b,
             field_pos_min_tiles=field_pos_min_tiles,
+            stack_axis=stack_axis,
         )
         return dev, 0, len(dev.fields) + len(dev.doc_values) + len(dev.vectors)
     n = max(segment.num_docs, pad_docs_to)
@@ -437,9 +449,10 @@ def pack_segment_delta(
             k1=k1,
             b=b,
             field_pos_min_tiles=field_pos_min_tiles,
+            stack_axis=stack_axis,
         )
         return dev, 0, len(dev.fields) + len(dev.doc_values) + len(dev.vectors)
-    put = lambda x: jax.device_put(x, device)
+    put = _putter(device, stack_axis)
     min_tiles = field_min_tiles or {}
     avgdls = field_avgdl or {}
     pos_min_tiles = field_pos_min_tiles or {}
@@ -466,6 +479,7 @@ def pack_segment_delta(
                 k1,
                 b,
                 pos_min_tiles.get(name, 0),
+                stack_axis,
             )
             packed += 1
     doc_values: dict[str, jax.Array] = {}
@@ -858,6 +872,7 @@ def pack_segment(
     k1: float = 1.2,
     b: float = 0.75,
     field_pos_min_tiles: dict[str, int] | None = None,
+    stack_axis: bool = False,
 ) -> DeviceSegment:
     """Upload a whole Segment to the device (the 'refresh' step).
 
@@ -865,9 +880,10 @@ def pack_segment(
     shards' segments stack into one leading-axis array for mesh sharding
     (padding docs are dead: live=False, doc values NaN, never present).
     `field_avgdl` supplies the statistics scope for precomputed impacts.
+    `stack_axis` uploads every plane as a [1, ...] shard row (pack_field).
     """
     n = max(segment.num_docs, pad_docs_to)
-    put = lambda x: jax.device_put(x, device)
+    put = _putter(device, stack_axis)
     min_tiles = field_min_tiles or {}
     avgdls = field_avgdl or {}
     pos_min_tiles = field_pos_min_tiles or {}
@@ -881,6 +897,7 @@ def pack_segment(
             k1,
             b,
             pos_min_tiles.get(name, 0),
+            stack_axis,
         )
         for name, f in segment.fields.items()
     }
@@ -900,7 +917,9 @@ def pack_segment(
         live[deleted] = False
     nested = {
         path: (
-            pack_segment(block.seg, device=device, k1=k1, b=b),
+            pack_segment(
+                block.seg, device=device, k1=k1, b=b, stack_axis=stack_axis
+            ),
             put(block.parent_of.astype(np.int32)),
         )
         for path, block in segment.nested.items()

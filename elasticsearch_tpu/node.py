@@ -608,6 +608,11 @@ class Node:
             )
         merge_cfg = settings.get("index", {}).get("merge", {})
         idx_dir = self._index_dir(name)
+        from .parallel.mesh_serving import maybe_mesh_view, mesh_devices
+
+        # An index the SPMD mesh will serve packs shard i's planes on mesh
+        # device i; otherwise every engine packs on the default device.
+        devices = mesh_devices(n_shards)
         engines = []
         for shard in range(n_shards):
             shard_path = idx_dir
@@ -617,6 +622,7 @@ class Node:
                 Engine(
                     mappings,
                     params=params,
+                    device=devices[shard] if devices else None,
                     data_path=shard_path,
                     durability=durability,
                     max_segments=int(merge_cfg.get("max_segment_count", 10)),
@@ -631,6 +637,8 @@ class Node:
         # name instead of `_node`.
         for engine in engines:
             self.hbm_ledger.name_scope(engine.uid, name)
+            if engine.device is not None:
+                self.hbm_ledger.place_scope(engine.uid, engine.device)
         self.hbm_ledger.name_scope(mesh_cache_scope(engines), name)
         search: SearchService | ShardedSearchCoordinator
         if n_shards == 1:
@@ -645,10 +653,9 @@ class Node:
                 device=self.device, filter_cache=self.filter_cache,
                 ann_cache=self.ann_cache,
             )
-            from .parallel.mesh_serving import maybe_mesh_view
-
             search.mesh_view = maybe_mesh_view(
-                engines, mappings, params, filter_cache=self.filter_cache
+                engines, mappings, params, devices,
+                filter_cache=self.filter_cache,
             )
             if search.mesh_view is not None:
                 # SPMD servings feed the same cost model/counters so
@@ -4678,23 +4685,26 @@ class Node:
         return rows
 
     def cat_hbm(self) -> list[dict]:
-        """GET /_cat/hbm — the HBM ledger's per-(label, index) resident
-        device bytes, one row per sample, read from the FANNED per-node
-        `device.hbm` sections (nodes_stats), so a clustered front shows
-        every member's residency; `?format=json` behaves like every cat
-        handler (the response is the row list)."""
+        """GET /_cat/hbm — the HBM ledger's per-(label, index, device)
+        resident device bytes, one row per sample, read from the FANNED
+        per-node `device.hbm` sections (nodes_stats), so a clustered
+        front shows every member's residency; `?format=json` behaves like
+        every cat handler (the response is the row list)."""
         rows: list[dict] = []
         for node_name, section in sorted(self.nodes_stats()["nodes"].items()):
             hbm = (section.get("device") or {}).get("hbm") or {}
             for entry in hbm.get("by_label_index", []):
-                rows.append(
-                    {
-                        "node": node_name,
-                        "label": str(entry.get("label", "")),
-                        "index": str(entry.get("index", "")),
-                        "bytes": str(int(entry.get("bytes", 0))),
-                    }
-                )
+                row = {
+                    "node": node_name,
+                    "label": str(entry.get("label", "")),
+                    "index": str(entry.get("index", "")),
+                    "bytes": str(int(entry.get("bytes", 0))),
+                }
+                # The ledger names the device holding the bytes; computed
+                # member sections carry no placement.
+                if "device" in entry:
+                    row["device"] = str(entry["device"])
+                rows.append(row)
             total_row = {
                 "node": node_name,
                 "label": "_total",

@@ -13,9 +13,11 @@ Three pieces, all feeding `_nodes/stats` and `GET /_metrics`:
   cannot drift — the consistency law (tests/test_device_obs.py): ledger
   totals equal the sum of each component's own byte stats through
   refresh / evict / `_cache/clear` / delete_index cycles, drift zero.
-  Surfaced as `estpu_hbm_bytes{label,index}` gauges + a high-watermark
-  gauge, the `device.hbm` section of `_nodes/stats` (fanned per node via
-  the PR-13 scatter), and `GET /_cat/hbm`.
+  Each registration also names the device that holds the bytes (an
+  engine's placement, a mesh shard's chip; the default device
+  otherwise). Surfaced as `estpu_hbm_bytes{label,index,device}` gauges
+  + a high-watermark gauge, the `device.hbm` section of `_nodes/stats`
+  (fanned per node via the PR-13 scatter), and `GET /_cat/hbm`.
 
 - **Compile census** — a process-wide `jax.monitoring` listener counts
   REAL backend compiles (`/jax/core/compile/backend_compile_duration`),
@@ -168,11 +170,26 @@ def ensure_compile_listener() -> None:
 # ---------------------------------------------------------------------------
 
 
+def device_label(device) -> str:
+    """How the ledger names a device: `<platform>:<id>` (`tpu:2`)."""
+    return f"{device.platform}:{device.id}"
+
+
+def _default_device_label() -> str:
+    """The device an unplaced upload lands on: JAX's first device."""
+    import jax
+
+    return device_label(jax.devices()[0])
+
+
 class HbmLedger:
     """Byte accounting for everything resident on device, by (label,
-    scope). Scopes are the components' own cache-scope tokens (engine
-    uid, mesh scope tuple, "_packed"); `name_scope` maps them to index
-    names for the {label,index} gauge rendering. The breaker writes
+    scope, device). Scopes are the components' own cache-scope tokens
+    (engine uid, mesh scope tuple, "_packed"); `name_scope` maps them to
+    index names for the {label,index,device} gauge rendering, and
+    `place_scope` to the device a scope's uploads land on (the default
+    device unless placed, or unless a registration names its own). The
+    breaker writes
     through (`breaker_backed=True`), so `breaker_drift_bytes` is
     structurally zero; components the breaker does not guard (packed
     planes, mesh snapshots) register directly."""
@@ -180,8 +197,9 @@ class HbmLedger:
     def __init__(self, metrics=None, enabled: bool = True):
         self.enabled = enabled
         self._lock = threading.Lock()
-        self._bytes: dict[tuple[str, Any], int] = {}
+        self._bytes: dict[tuple[str, Any, str], int] = {}
         self._names: dict[Any, str] = {}
+        self._places: dict[Any, str] = {}
         self._total = 0
         self._hwm = 0
         self._breaker_bytes = 0
@@ -208,15 +226,36 @@ class HbmLedger:
         0 from then on)."""
         with self._lock:
             self._names[scope] = index_name
-            labels = {
-                label for (label, s) in self._bytes if s == scope
+            series = {
+                (label, dev) for (label, s, dev) in self._bytes if s == scope
             }
-        for label in labels:
-            self._ensure_gauge(label, index_name)
+        for label, dev in series:
+            self._ensure_gauge(label, index_name, dev)
+
+    def place_scope(self, scope: Any, device) -> None:
+        """Name the device a scope's uploads land on (an engine packed on
+        its mesh chip). Bytes the scope registered before it was placed
+        (boot recovery packs inside the engine's constructor) move to
+        that device's series."""
+        dev = device_label(device)
+        with self._lock:
+            self._places[scope] = dev
+            moved = [
+                (key, n) for key, n in self._bytes.items()
+                if key[1] == scope and key[2] != dev
+            ]
+            for (label, s, old), n in moved:
+                del self._bytes[(label, s, old)]
+                key = (label, s, dev)
+                self._bytes[key] = self._bytes.get(key, 0) + n
+            index = self._index_of(scope)
+        for label in {key[0] for key, _ in moved}:
+            self._ensure_gauge(label, index, dev)
 
     def forget_scope(self, scope: Any) -> None:
         with self._lock:
             self._names.pop(scope, None)
+            self._places.pop(scope, None)
 
     def _index_of(self, scope: Any) -> str:
         name = self._names.get(scope)
@@ -226,19 +265,27 @@ class HbmLedger:
 
     # --------------------------------------------------------- accounting
 
+    def _device_of(self, scope: Any, device) -> str:
+        if device is not None:
+            return device_label(device)
+        return self._places.get(scope) or _default_device_label()
+
     def register(
         self,
         label: str,
         scope: Any,
         nbytes: int,
         breaker_backed: bool = False,
+        device=None,
     ) -> None:
-        """Account `nbytes` landing on device under (label, scope)."""
+        """Account `nbytes` landing under (label, scope) on `device` (a
+        JAX device; default: where the scope is placed)."""
         if not self.enabled or nbytes <= 0:
             return
         nbytes = int(nbytes)
         base = _base_label(label)
-        key = (base, scope)
+        dev = self._device_of(scope, device)
+        key = (base, scope, dev)
         with self._lock:
             self._bytes[key] = self._bytes.get(key, 0) + nbytes
             self._total += nbytes
@@ -248,7 +295,7 @@ class HbmLedger:
                 self._breaker_bytes += nbytes
             index = self._index_of(scope)
         _proc_register(nbytes)
-        self._ensure_gauge(base, index)
+        self._ensure_gauge(base, index, dev)
 
     def release(
         self,
@@ -256,13 +303,14 @@ class HbmLedger:
         scope: Any,
         nbytes: int,
         breaker_backed: bool = False,
+        device=None,
     ) -> None:
         """Account `nbytes` leaving the device. Clamped per key: the
         ledger can never go negative, mirroring the breaker's own clamp."""
         if not self.enabled or nbytes <= 0:
             return
         nbytes = int(nbytes)
-        key = (_base_label(label), scope)
+        key = (_base_label(label), scope, self._device_of(scope, device))
         with self._lock:
             held = self._bytes.get(key, 0)
             taken = min(held, nbytes)
@@ -277,27 +325,30 @@ class HbmLedger:
                 self._breaker_bytes = max(0, self._breaker_bytes - nbytes)
         _proc_release(nbytes)
 
-    def _ensure_gauge(self, label: str, index: str) -> None:
+    def _ensure_gauge(self, label: str, index: str, device: str) -> None:
         if self.metrics is None:
             return
         with self._lock:
-            if (label, index) in self._gauged:
+            if (label, index, device) in self._gauged:
                 return
-            self._gauged.add((label, index))
+            self._gauged.add((label, index, device))
         self.metrics.gauge(
             "estpu_hbm_bytes",
-            "Device bytes resident per ledger label and index",
-            fn=lambda l=label, i=index: self._label_index_bytes(l, i),
+            "Device bytes resident per ledger label, index and device",
+            fn=lambda l=label, i=index, d=device: self._series_bytes(l, i, d),
             label=label,
             index=index,
+            device=device,
         )
 
-    def _label_index_bytes(self, label: str, index: str) -> int:
+    def _series_bytes(self, label: str, index: str, device: str) -> int:
         with self._lock:
             return sum(
                 n
-                for (lbl, scope), n in self._bytes.items()
-                if lbl == label and self._index_of(scope) == index
+                for (lbl, scope, dev), n in self._bytes.items()
+                if lbl == label
+                and dev == device
+                and self._index_of(scope) == index
             )
 
     # -------------------------------------------------------------- views
@@ -307,10 +358,10 @@ class HbmLedger:
         consistency-law accessor the tests gate on."""
         base = _base_label(label)
         with self._lock:
-            if scope is not None:
-                return self._bytes.get((base, scope), 0)
             return sum(
-                n for (lbl, _s), n in self._bytes.items() if lbl == base
+                n
+                for (lbl, s, _dev), n in self._bytes.items()
+                if lbl == base and (scope is None or s == scope)
             )
 
     @property
@@ -329,10 +380,10 @@ class HbmLedger:
             return self.disabled_snapshot()
         with self._lock:
             by_label: dict[str, int] = {}
-            rows: dict[tuple[str, str], int] = {}
-            for (label, scope), n in self._bytes.items():
+            rows: dict[tuple[str, str, str], int] = {}
+            for (label, scope, dev), n in self._bytes.items():
                 by_label[label] = by_label.get(label, 0) + n
-                rk = (label, self._index_of(scope))
+                rk = (label, self._index_of(scope), dev)
                 rows[rk] = rows.get(rk, 0) + n
             total = self._total
             hwm = self._hwm
@@ -343,8 +394,9 @@ class HbmLedger:
             "high_watermark_bytes": hwm,
             "by_label": {k: by_label[k] for k in sorted(by_label)},
             "by_label_index": [
-                {"label": label, "index": index, "bytes": rows[(label, index)]}
-                for label, index in sorted(rows)
+                {"label": label, "index": index, "device": dev,
+                 "bytes": rows[(label, index, dev)]}
+                for label, index, dev in sorted(rows)
             ],
         }
         if self.breaker is not None:

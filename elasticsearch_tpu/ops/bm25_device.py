@@ -1975,6 +1975,15 @@ def compute_filter_mask(seg, spec, arrays):
 
 
 @partial(jax.jit, static_argnames=("spec",))
+def compute_filter_mask_row(seg_row, spec, arrays):
+    """compute_filter_mask over one shard row of a mesh (every leaf
+    [1, ...], as MeshView packs them): the [1, N] row of the stacked
+    [S, N] plane, computed on the row's own device."""
+    seg = jax.tree.map(lambda x: x[0], seg_row)
+    return compute_filter_mask(seg, spec, arrays)[None]
+
+
+@partial(jax.jit, static_argnames=("spec",))
 def compute_filter_mask_stacked(seg_stacked, spec, arrays_stacked):
     """Per-shard filter-mask planes over S stacked shards ([S, N] bool)
     — the mesh-path (parallel/sharded.py) form of compute_filter_mask."""

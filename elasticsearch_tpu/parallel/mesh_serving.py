@@ -14,7 +14,11 @@ Design:
 
 - `MeshView` maintains a device-resident "searchable snapshot" of the
   index: one merged segment per shard (its engine's live docs, in
-  host-path global-doc order), packed onto that shard's mesh device.
+  host-path global-doc order), packed onto that shard's mesh device as
+  [1, ...] rows that the stacked arrays are assembled from (one copy).
+  The engines themselves pack on the same devices (`mesh_devices` places
+  the index before its engines are built), so a chip holds only its own
+  shard.
 - **Delta-scaled refresh** (ROADMAP item 4): per-shard buffers are keyed
   by the engine's monotonic refresh `generation`; a search only re-merges
   shards whose generation moved. Within a changed shard, the merge is
@@ -78,9 +82,11 @@ tests/test_mesh_sorted_aggs.py fuzz suite.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field as dc_field
 from typing import Any
 
@@ -91,19 +97,41 @@ from ..index.merge import compact_segment, concat_segments
 from ..index.segment import Segment
 from ..index.tiles import TILE, device_nbytes, pack_segment_delta
 from ..obs.metrics import timed_launch
-from ..ops.bm25_device import segment_tree
-from ..query.compile import FieldStats, aggregate_field_stats
+from ..obs.tracing import TRACER
+from ..ops.bm25_device import segment_tree, supports_maxscore
+from ..query.compile import (
+    CompiledQuery,
+    Compiler,
+    FieldStats,
+    SpecUnifyError,
+    aggregate_field_stats,
+    equalize_compiled,
+)
 from .sharded import (
     ShardedIndex,
     fill_union_schema,
     sharded_execute,
+    sharded_execute_maxscore,
     sharded_execute_request,
+    pack_plan,
+    stack_maxscore,
+    stack_plans,
     union_schema,
 )
 
 
 def _pow2(n: int, floor: int = 1) -> int:
     return 1 << max(0, max(n, floor) - 1).bit_length()
+
+
+def _wait(timed, out):
+    """The host blocked on one SPMD launch's outputs (`mesh.wait`): the
+    launch timer's own wait when instrumented, else block here, so the
+    span means the same either way."""
+    import jax
+
+    with TRACER.span("mesh.wait"):
+        return jax.block_until_ready(timed.dispatched(out))
 
 
 # Error classification for the serving breaker. Sticky failures are wrong-
@@ -261,9 +289,10 @@ class MeshIndex(ShardedIndex):
     serving_stats: dict[str, FieldStats] | None = None
     pack_avgdls: list[dict[str, float]] | None = None
     # Per-shard content signatures — tuple of (handle uid, live epoch)
-    # per shard — and per-shard (non-stacked) device seg trees: the
-    # row-granular filter-cache machinery below keys mask-plane rows on
-    # the former and rebuilds a single missing row on the latter.
+    # per shard — and per-shard device seg trees (each leaf the [1, ...]
+    # row the stacked array is assembled from): the row-granular
+    # filter-cache machinery below keys mask-plane rows on the former and
+    # rebuilds a single missing row on the latter.
     shard_sigs: tuple = ()
     shard_trees: list = dc_field(default_factory=list)
 
@@ -282,8 +311,8 @@ class MeshIndex(ShardedIndex):
         The [S, N] stacked plane the kernel consumes is re-assembled
         zero-copy from the cached rows (each row already lives on its
         shard's mesh device); a missing row is rebuilt with a
-        single-shard `compute_filter_mask` launch against that shard's
-        own seg tree. Bit-exactness holds because the stacked builder
+        single-shard `compute_filter_mask_row` launch against that
+        shard's own row of the stacked planes. Bit-exactness holds because the stacked builder
         was itself a vmap of the same per-shard mask program
         (ops/bm25_device.compute_filter_mask_stacked), gated by the
         tests/test_mesh_refresh.py fuzz. The assembled [S, N] view is
@@ -298,7 +327,7 @@ class MeshIndex(ShardedIndex):
             apply_cached_masks,
             record_filter_usage,
         )
-        from ..ops.bm25_device import compute_filter_mask
+        from ..ops.bm25_device import compute_filter_mask_row
 
         if entries is None:
             entries = record_filter_usage(cache, query, record=record)
@@ -327,9 +356,9 @@ class MeshIndex(ShardedIndex):
                     arrays_s = jax.tree.map(
                         lambda x: x[s], child_arrays
                     )
-                    row = compute_filter_mask(
+                    row = compute_filter_mask_row(
                         self.shard_trees[s], child_spec, arrays_s
-                    ).reshape(1, -1)
+                    )
                     cache.put(rkey, row, int(row.nbytes))
                 else:
                     hit_rows += 1
@@ -423,8 +452,11 @@ class MeshView:
         self._shard_sig: list[tuple | None] = [None] * n
         # Union-schema-filled copies actually packed (what snapshots see).
         self._filled_segs: list[Segment | None] = [None] * n
-        self._trees: list[Any] = [None] * n  # [1, ...]-leaved device pytrees
-        self._devs: list[Any] = [None] * n  # packed DeviceSegments (views)
+        # Packed DeviceSegments, every device array a [1, ...] shard row,
+        # and their jit-input pytrees: the stacked arrays are assembled
+        # from these very buffers, so the mesh holds one copy of a shard.
+        self._trees: list[Any] = [None] * n
+        self._devs: list[Any] = [None] * n
         self._spans: list[list] = [[] for _ in range(n)]  # handle spans
         self._pack_avgdl: list[dict[str, float]] = [{} for _ in range(n)]
         self._shapes: dict[str, Any] | None = None  # current padded shapes
@@ -464,6 +496,7 @@ class MeshView:
         self.device = None
         self.ledger = None
         self.plane_bytes = 0  # current snapshot's registered device bytes
+        self._shard_plane_bytes = [0] * n  # the same, by shard (its chip)
 
     @property
     def disabled(self) -> bool:
@@ -563,11 +596,15 @@ class MeshView:
                 return False
         return True
 
+    def _shard_device(self, shard: int):
+        return self.mesh.devices.reshape(-1)[shard]
+
     def _pack_shard(self, shard: int, seg: Segment, shapes: dict[str, Any],
                     stats: dict[str, FieldStats],
                     delta_ok: bool = False):
-        """Pack one shard's merged segment onto its mesh device; leaves get
-        a leading [1, ...] axis for the global-array assembly. Returns
+        """Pack one shard's merged segment onto its mesh device, every
+        plane uploaded as a [1, ...] row for the global-array assembly (no
+        second, stacked copy). Returns
         (tree, filled segment, pack avgdls) — the caller commits them into
         the per-shard caches only once EVERY shard packed, so a mid-rebuild
         failure can't leave mixed-shape buffers behind.
@@ -583,9 +620,7 @@ class MeshView:
         `seg` stays pristine in the per-shard cache, and segments held by a
         previous, still-serving snapshot are never mutated under a
         concurrent compile."""
-        import jax
-
-        device = self.mesh.devices.reshape(-1)[shard]
+        device = self._shard_device(shard)
         seg = fill_union_schema(
             seg, shapes["fields"], shapes["dv"], shapes["vec"]
         )
@@ -606,6 +641,7 @@ class MeshView:
             k1=self.params.k1,
             b=self.params.b,
             field_pos_min_tiles=shapes["pos_tiles"],
+            stack_axis=True,
         )
         if reused or packed:
             self.metrics.counter(
@@ -622,8 +658,7 @@ class MeshView:
         # in-program aggregation planes.
         from ..ops.aggs_device import agg_segment_tree
 
-        tree = jax.tree.map(lambda x: x[None], agg_segment_tree(dev))
-        return tree, seg, avgdl, dev
+        return agg_segment_tree(dev), seg, avgdl, dev
 
     def _assemble(self) -> Any:
         """Zero-copy global stacked pytree from the per-shard buffers."""
@@ -678,8 +713,6 @@ class MeshView:
             snap = self._snap
             if snap is not None and snap.gens == gens:
                 return snap
-            import jax
-
             n = len(self.engines)
             # Content signatures: a generation bump whose shard signature
             # is unchanged (e.g. another shard's write) needs no re-merge.
@@ -729,11 +762,15 @@ class MeshView:
             # Stage every pack, then commit atomically: a failure here
             # leaves all per-shard caches untouched (old snapshot keeps
             # serving; the gen mismatch retries the refresh next search).
-            packed = {
-                i: self._pack_shard(i, merged[i], shapes, stats,
-                                    delta_ok=delta_ok)
-                for i in to_pack
-            }
+            # Shards pack side by side, each onto its own device: the
+            # host work is numpy that releases the interpreter lock.
+            with ThreadPoolExecutor(max(1, len(to_pack))) as pool:
+                packed = dict(zip(to_pack, pool.map(
+                    lambda i: self._pack_shard(
+                        i, merged[i], shapes, stats, delta_ok=delta_ok
+                    ),
+                    to_pack,
+                )))
             if shapes is not self._shapes:
                 self._shapes = shapes
                 self.rebuilds += 1
@@ -768,20 +805,25 @@ class MeshView:
                     ("row", s, sigs[s], docs_pad) for s in range(n)
                 }
                 self.filter_cache.purge_scope(scope, keep)
-            # HBM ledger: this snapshot's resident device bytes (shared
-            # delta-reused planes count once — device_nbytes walks the
-            # CURRENT views). The registration swaps atomically with the
-            # snapshot commit; the consistency-law twin is plane_bytes.
-            nbytes = sum(
-                device_nbytes(d) for d in self._devs if d is not None
-            )
+            # HBM ledger: this snapshot's resident device bytes on each
+            # shard's chip (shared delta-reused planes count once —
+            # device_nbytes walks the CURRENT views). The registration
+            # swaps atomically with the snapshot commit; the
+            # consistency-law twin is plane_bytes.
+            by_shard = [
+                device_nbytes(d) if d is not None else 0 for d in self._devs
+            ]
             if self.ledger is not None:
                 # Register BEFORE releasing the previous snapshot's
                 # bytes: both snapshots coexist across the swap (delta
                 # reuse aside), and the high watermark must see it.
-                self.ledger.register("mesh_plane", scope, nbytes)
-                self.ledger.release("mesh_plane", scope, self.plane_bytes)
-            self.plane_bytes = nbytes
+                for i, nb in enumerate(by_shard):
+                    self.ledger.register(
+                        "mesh_plane", scope, nb, device=self._shard_device(i)
+                    )
+                self._release_plane_bytes(scope)
+            self._shard_plane_bytes = by_shard
+            self.plane_bytes = sum(by_shard)
             segments = [s for s in self._filled_segs]
             index = MeshIndex(
                 mesh=self.mesh,
@@ -797,9 +839,7 @@ class MeshView:
                 cache_scope=scope,
                 cache_generation=sum(gens),
                 shard_sigs=tuple(sigs),
-                shard_trees=[
-                    jax.tree.map(lambda x: x[0], t) for t in self._trees
-                ],
+                shard_trees=list(self._trees),
             )
             self._snap = _Snapshot(
                 gens=gens,
@@ -812,14 +852,18 @@ class MeshView:
             )
             return self._snap
 
+    def _release_plane_bytes(self, scope) -> None:
+        for i, nb in enumerate(self._shard_plane_bytes):
+            self.ledger.release(
+                "mesh_plane", scope, nb, device=self._shard_device(i)
+            )
+
     def release_ledger(self) -> None:
         """Release this view's mesh-plane ledger registration (index
         deletion: the snapshot's device arrays die with the view)."""
         if self.ledger is not None and self.plane_bytes:
-            self.ledger.release(
-                "mesh_plane", mesh_cache_scope(self.engines),
-                self.plane_bytes,
-            )
+            self._release_plane_bytes(mesh_cache_scope(self.engines))
+        self._shard_plane_bytes = [0] * len(self.engines)
         self.plane_bytes = 0
 
     # -------------------------------------------------------------- serve
@@ -830,8 +874,6 @@ class MeshView:
         is attached to the ENCLOSING mesh.serve span as an event from this
         thread's own trace context (race-free under concurrent searches);
         last_fallback_reason is a single-threaded test/diagnostic hook."""
-        from ..obs.tracing import TRACER
-
         self.last_fallback_reason = reason
         with self._lock:
             self.fallbacks[reason] = self.fallbacks.get(reason, 0) + 1
@@ -954,6 +996,113 @@ class MeshView:
         )
         return agg, per_shard[0][0], arrays
 
+    def _shard_compiler(self, snap: _Snapshot, shard: int) -> Compiler:
+        """The planner of one shard's packed planes under the snapshot's
+        pushed-down statistics, as an engine plans its own segment
+        (Engine.compiler_for): MaxScore reads the host-side per-tile
+        impact maxima and span extents the pack keeps."""
+        dev = snap.handles[shard].device
+        return Compiler(
+            fields=dev.fields,
+            doc_values=dev.doc_values,
+            mappings=self.mappings,
+            params=self.params,
+            stats=snap.index.field_stats(),
+        )
+
+    def _compile_maxscore(self, snap: _Snapshot, query, threshold: int):
+        """The stacked plan of MaxScore traffic (query/compile.Compiler.
+        compile_maxscore on every shard, `stack_maxscore`), or None for any
+        other query, whose plan `ShardedIndex.compile` makes as before.
+        Where any shard's split declines, every shard takes the exhaustive
+        plan, so the launch stays one program."""
+        n = len(self.engines)
+        plans = [
+            self._shard_compiler(snap, i).compile_maxscore(query, threshold)
+            for i in range(n)
+        ]
+        if any(p is None for p in plans):
+            return None
+        if not all(supports_maxscore(p.spec) for p in plans):
+            if self.device is not None:
+                self.device.maxscore("bypass")
+            return self._compile_exhaustive(snap, query)
+        return stack_maxscore(plans)
+
+    def _compile_exhaustive(self, snap: _Snapshot, query):
+        """The stacked exhaustive plan of MaxScore traffic (Compiler.
+        compile_exhaustive on every shard): for a declined split and for
+        a MaxScore launch whose check failed. Its few shapes are shared
+        by all such queries."""
+        plans = [
+            self._shard_compiler(snap, i).compile_exhaustive(query)
+            for i in range(len(self.engines))
+        ]
+        try:
+            plans = equalize_compiled(plans)
+        except SpecUnifyError:
+            return snap.index.compile(query)
+        return CompiledQuery(
+            spec=plans[0].spec, arrays=stack_plans([p.arrays for p in plans])
+        )
+
+    def _serve_maxscore(self, idx, compiled, k: int):
+        """(scores, global ids, total) of one MaxScore launch, as host
+        arrays, or None when its threshold check failed and the exhaustive
+        plan must answer. Outcomes count on estpu_sparse_maxscore_total."""
+        import jax
+
+        with timed_launch(
+            self.device, "mesh_spmd", (compiled.spec, k, None, False, "plain"),
+            "mesh_spmd",
+        ) as tl:
+            with TRACER.span("mesh.dispatch"):
+                buf, layout = pack_plan(compiled.arrays)
+                out = sharded_execute_maxscore(
+                    idx.mesh, idx.axis, idx.seg_stacked, buf, compiled.spec, k,
+                    idx.docs_per_shard, layout,
+                )
+            out = _wait(tl, out)
+        with TRACER.span("mesh.fetch"):
+            scores, gids, total, ok = jax.device_get(out)
+        ok = bool(ok)
+        if self.device is not None:
+            self.device.maxscore("served" if ok else "fallback")
+        return (scores, gids, total) if ok else None
+
+    def _serve_plain(self, idx, request, compiled, k: int, fc_entries):
+        """(scores, global ids, total) of the plain-score launch."""
+        # The hot plain-score path keeps the candidate-centric sparse
+        # kernel (no dense planes, no agg planes). Filter cache: repeated
+        # filter clauses swap in their cached [S, N] mask planes
+        # (bit-identical by construction — gated by
+        # tests/test_filter_cache.py's mesh fuzz); the sorted/agg
+        # one-launch program still recomputes filters (honest residue,
+        # ROADMAP item 3).
+        seg = idx.seg_stacked
+        if idx.filter_cache is not None:
+            # record=False: the coordinator already counted this
+            # request's sighting; recording here too would double-count
+            # whenever execution fails and the request falls back to the
+            # host loop. Its collected entries ride along so the AST
+            # isn't re-walked.
+            with TRACER.span("mesh.compile"):
+                compiled, fc_masks = idx._apply_filter_cache(
+                    request.query, compiled, record=False, entries=fc_entries,
+                )
+            if fc_masks:
+                seg = {**idx.seg_stacked, "masks": fc_masks}
+        with timed_launch(
+            self.device, "mesh_spmd", (compiled.spec, k, None, False, "plain"),
+            "mesh_spmd",
+        ) as tl:
+            with TRACER.span("mesh.dispatch"):
+                out = sharded_execute(
+                    idx.mesh, idx.axis, seg, compiled.arrays, compiled.spec, k,
+                    idx.docs_per_shard,
+                )
+            return _wait(tl, out)
+
     def serve(self, coordinator, request, task=None, fc_entries=None):
         """Answer a SearchRequest via ONE SPMD program — scoring, sorted or
         score-ordered top-k with search_after masking, psum'd totals, and
@@ -979,28 +1128,45 @@ class MeshView:
         if isinstance(sort_plan, str):
             return self._fallback(sort_plan)
         sort_field, sort_desc, missing_first, want_sort_values = sort_plan
+        k = max(0, request.from_) + max(0, request.size)
+        # A plain score-sorted top-k with an integer track_total_hits may
+        # take the MaxScore plan (`_compile_maxscore`).
+        maxscore_threshold = None
+        if (
+            sort_field is None
+            and request.search_after is None
+            and request.aggs is None
+            and not want_sort_values
+            and k > 0
+            and not isinstance(request.track_total_hits, bool)
+        ):
+            maxscore_threshold = int(request.track_total_hits)
         start = time.monotonic()
-        snap = self._ensure()
+        with TRACER.span("mesh.refresh"):
+            snap = self._ensure()
         idx = snap.index
-        try:
-            compiled = idx.compile(request.query)
-        # staticcheck: ignore[broad-except] compile fallback: non-shard-uniform plans route to the host loop, which re-raises user-facing validation errors identically
-        except Exception:
-            # Plans the mesh can't make shard-uniform fall back; user-facing
-            # validation errors re-raise identically from the host path.
-            return self._fallback("non_uniform_plan")
         agg = None
         aggs_spec = None
         aggs_arrays = ()
-        if request.aggs is not None:
+        with TRACER.span("mesh.compile"):
             try:
-                agg, aggs_spec, aggs_arrays = self._compile_aggs(
-                    coordinator, snap, request
-                )
-            # staticcheck: ignore[broad-except] agg-compile fallback: the host loop re-raises user-facing agg validation errors (text-field terms, bad params) identically
+                compiled = None
+                if maxscore_threshold is not None:
+                    compiled = self._compile_maxscore(
+                        snap, request.query, maxscore_threshold
+                    )
+                if compiled is None:
+                    compiled = idx.compile(request.query)
+                if request.aggs is not None:
+                    agg, aggs_spec, aggs_arrays = self._compile_aggs(
+                        coordinator, snap, request
+                    )
+            # staticcheck: ignore[broad-except] compile fallback: non-shard-uniform plans route to the host loop, which re-raises user-facing query and agg validation errors identically
             except Exception:
+                # Plans (query or aggregations) the mesh can't make
+                # shard-uniform fall back; user-facing validation errors
+                # re-raise identically from the host path.
                 return self._fallback("non_uniform_plan")
-        k = max(0, request.from_) + max(0, request.size)
         if sort_field is not None and k > 0 and sort_field not in (
             idx.segments[0].doc_values if idx.segments else {}
         ):
@@ -1035,45 +1201,24 @@ class MeshView:
             and not want_sort_values
             and k > 0
         )
+        fetch = contextlib.ExitStack()
         try:
             if plain:
-                # The hot plain-score path keeps the candidate-centric
-                # sparse kernel (no dense planes, no agg planes). Filter
-                # cache: repeated filter clauses swap in their cached
-                # [S, N] mask planes (bit-identical by construction —
-                # gated by tests/test_filter_cache.py's mesh fuzz); the
-                # sorted/agg one-launch program still recomputes filters
-                # (honest residue, ROADMAP item 3).
-                seg = idx.seg_stacked
-                if idx.filter_cache is not None:
-                    # record=False: the coordinator already counted this
-                    # request's sighting; recording here too would
-                    # double-count whenever execution fails and the
-                    # request falls back to the host loop. Its collected
-                    # entries ride along so the AST isn't re-walked.
-                    compiled, fc_masks = idx._apply_filter_cache(
-                        request.query, compiled, record=False,
-                        entries=fc_entries,
+                # MaxScore traffic launches its MaxScore plan first; a
+                # failed check re-plans exhaustively, still on the mesh.
+                answer = None
+                if supports_maxscore(compiled.spec):
+                    answer = self._serve_maxscore(idx, compiled, k)
+                    if answer is None:
+                        with TRACER.span("mesh.compile"):
+                            compiled = self._compile_exhaustive(
+                                snap, request.query
+                            )
+                if answer is None:
+                    answer = self._serve_plain(
+                        idx, request, compiled, k, fc_entries
                     )
-                    if fc_masks:
-                        seg = {**idx.seg_stacked, "masks": fc_masks}
-                with timed_launch(
-                    self.device,
-                    "mesh_spmd",
-                    (compiled.spec, k, None, False, "plain"),
-                    "mesh_spmd",
-                ) as tl:
-                    scores, gids, total = tl.dispatched(
-                        sharded_execute(
-                            idx.mesh,
-                            idx.axis,
-                            seg,
-                            compiled.arrays,
-                            compiled.spec,
-                            k,
-                            idx.docs_per_shard,
-                        )
-                    )
+                scores, gids, total = answer
                 keys = vals = None
                 n_after = total
                 agg_out = ()
@@ -1087,8 +1232,8 @@ class MeshView:
                     ),
                     "mesh_spmd",
                 ) as tl:
-                    keys, vals, gids, total, n_after, agg_out = tl.dispatched(
-                        sharded_execute_request(
+                    with TRACER.span("mesh.dispatch"):
+                        out = sharded_execute_request(
                             idx.mesh,
                             idx.axis,
                             idx.seg_stacked,
@@ -1105,13 +1250,17 @@ class MeshView:
                             aggs_spec=aggs_spec,
                             aggs_arrays_stacked=aggs_arrays,
                         )
-                    )
+                    keys, vals, gids, total, n_after, agg_out = _wait(tl, out)
                 scores = vals
             import jax
 
-            scores = np.asarray(scores) if scores is not None else None
-            gids = np.asarray(gids)
-            agg_np = jax.device_get(agg_out)
+            # From here to the response: what the host does with the
+            # program's outputs (device_get, hits, the response).
+            fetch.enter_context(TRACER.span("mesh.fetch"))
+            # One batched transfer of every output.
+            scores, gids, total, n_after, agg_np = jax.device_get(
+                (scores, gids, total, n_after, agg_out)
+            )
             total = int(total)
             n_after = int(n_after)
         # staticcheck: ignore[broad-except] execute failures (incl. injected ones) must feed the mesh circuit breaker and fall back — the breaker's error classification is the tested behavior
@@ -1120,101 +1269,113 @@ class MeshView:
             # mesh copy): fall back to the host loop and feed the breaker —
             # transient failures re-enable after a cooldown'd success,
             # sticky (compile/parity) failures latch off for good.
+            fetch.close()
             self.exec_failures += 1
             self.breaker.record_failure(e)
             return self._fallback("execute_error")
-        self.breaker.record_success()
-        self.served += 1
-        shape = "plain" if sort_field is None else "sorted"
-        if aggs_spec is not None:
-            shape = shape + "_aggs" if k > 0 else "aggs_only"
-        elif k == 0:
-            shape = "count_only"
-        self.metrics.counter(
-            "estpu_mesh_served_total",
-            "Searches served by the one-launch SPMD program, by shape",
-            shape=shape,
-        ).inc()
-        if self.planner is not None:
-            self.planner.record(
-                ("mesh", compiled.spec, k, sort_field, aggs_spec is not None),
-                "mesh_spmd",
-                time.monotonic() - start,
-            )
-        aggregations = None
-        if agg is not None:
-            from ..search.aggs import merge_mesh_result
-
-            states = [new_merge_state(n) for n in request.aggs]
-            for node, state, res in zip(request.aggs, states, agg_np):
-                merge_mesh_result(node, state, res, snap.handles)
-            aggregations = agg.render_states(states)
-        timed_out = bool(task is not None and task.check_deadline())
-        limit = n_after if has_after else total
-        n = min(k, limit, len(gids))
-        max_score = None
-        if request.sort is None and scores is not None and n > 0:
-            max_score = float(scores[0])
-        hits = []
-        svc = coordinator.services[0]
-        for rank in range(max(0, request.from_), n):
-            shard, local = idx.locate(int(gids[rank]))
-            handle = snap.handles[shard]
-            score = None
-            sort_out = None
-            if sort_field is not None:
-                raw = float(scores[rank])
-                sort_out = [None if np.isnan(scores[rank]) else raw]
-            else:
-                score = float(scores[rank])
-                if want_sort_values:
-                    sort_out = [score]
-            hits.append(
-                SearchHit(
-                    doc_id=handle.segment.ids[local],
-                    score=score,
-                    source=svc._fetch_source(handle, local, request),
-                    sort=sort_out,
-                    global_doc=-1,
-                    handle=handle,
-                    local=local,
+        with fetch:
+            self.breaker.record_success()
+            self.served += 1
+            shape = "plain" if sort_field is None else "sorted"
+            if aggs_spec is not None:
+                shape = shape + "_aggs" if k > 0 else "aggs_only"
+            elif k == 0:
+                shape = "count_only"
+            self.metrics.counter(
+                "estpu_mesh_served_total",
+                "Searches served by the one-launch SPMD program, by shape",
+                shape=shape,
+            ).inc()
+            if self.planner is not None:
+                self.planner.record(
+                    ("mesh", compiled.spec, k, sort_field, aggs_spec is not None),
+                    "mesh_spmd",
+                    time.monotonic() - start,
                 )
+            aggregations = None
+            if agg is not None:
+                from ..search.aggs import merge_mesh_result
+
+                states = [new_merge_state(n) for n in request.aggs]
+                for node, state, res in zip(request.aggs, states, agg_np):
+                    merge_mesh_result(node, state, res, snap.handles)
+                aggregations = agg.render_states(states)
+            timed_out = bool(task is not None and task.check_deadline())
+            limit = n_after if has_after else total
+            n = min(k, limit, len(gids))
+            max_score = None
+            if request.sort is None and scores is not None and n > 0:
+                max_score = float(scores[0])
+            hits = []
+            svc = coordinator.services[0]
+            for rank in range(max(0, request.from_), n):
+                shard, local = idx.locate(int(gids[rank]))
+                handle = snap.handles[shard]
+                score = None
+                sort_out = None
+                if sort_field is not None:
+                    raw = float(scores[rank])
+                    sort_out = [None if np.isnan(scores[rank]) else raw]
+                else:
+                    score = float(scores[rank])
+                    if want_sort_values:
+                        sort_out = [score]
+                hits.append(
+                    SearchHit(
+                        doc_id=handle.segment.ids[local],
+                        score=score,
+                        source=svc._fetch_source(handle, local, request),
+                        sort=sort_out,
+                        global_doc=-1,
+                        handle=handle,
+                        local=local,
+                    )
+                )
+            coordinator._apply_fetch_subphases(request, hits)
+            total_out, relation = clamp_total(total, request.track_total_hits)
+            return SearchResponse(
+                took_ms=int((time.monotonic() - start) * 1000),
+                total=total_out,
+                total_relation=relation,
+                max_score=max_score,
+                hits=hits,
+                aggregations=aggregations,
+                shards=len(self.engines),
+                timed_out=timed_out,
             )
-        coordinator._apply_fetch_subphases(request, hits)
-        total_out, relation = clamp_total(total, request.track_total_hits)
-        return SearchResponse(
-            took_ms=int((time.monotonic() - start) * 1000),
-            total=total_out,
-            total_relation=relation,
-            max_score=max_score,
-            hits=hits,
-            aggregations=aggregations,
-            shards=len(self.engines),
-            timed_out=timed_out,
-        )
 
 
-def maybe_mesh_view(
-    engines, mappings, params, filter_cache=None
-) -> MeshView | None:
-    """A MeshView when SPMD serving can work here: >1 shard, enough local
-    devices for one shard per device, and not disabled via
-    ESTPU_MESH_SERVING=0."""
-    if len(engines) < 2:
+def mesh_devices(n_shards: int) -> list | None:
+    """The devices an index of `n_shards` is served on, shard i on the
+    i-th, when SPMD serving can work here: >1 shard, enough local devices
+    for one shard per device, and not disabled via ESTPU_MESH_SERVING=0.
+    None means the host loop over engines on the default device. Decided
+    before the engines are built, so each packs its planes on its own
+    shard's device."""
+    if n_shards < 2:
         return None
     if os.environ.get("ESTPU_MESH_SERVING", "1") == "0":
         return None
     try:
         import jax
-        from jax.sharding import Mesh
 
         devices = jax.devices()
     # staticcheck: ignore[broad-except] device-probe guard: no usable mesh means host-loop serving, not an error
     except Exception:
         return None
-    if len(devices) < len(engines):
+    if len(devices) < n_shards:
         return None
-    mesh = Mesh(
-        np.array(devices[: len(engines)]), ("shard",)
-    )
+    return list(devices[:n_shards])
+
+
+def maybe_mesh_view(
+    engines, mappings, params, devices, filter_cache=None
+) -> MeshView | None:
+    """A MeshView of the engines over `devices` (`mesh_devices`, shard i
+    on the i-th), or None when there are none: the host loop serves."""
+    if devices is None:
+        return None
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(devices), ("shard",))
     return MeshView(engines, mappings, params, mesh, filter_cache=filter_cache)

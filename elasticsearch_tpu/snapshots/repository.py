@@ -25,6 +25,7 @@ import re
 import shutil
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any
 
 import numpy as np
@@ -291,8 +292,8 @@ class FsRepository:
                     },
                 )
                 svc = node.indices[target]
-                for shard_idx, shard_meta in enumerate(meta["shards"]):
-                    engine = svc.engines[shard_idx]
+
+                def restore_shard(engine, shard_meta):
                     batch = []
                     for seg_meta in shard_meta["segments"]:
                         blob_dir = os.path.join(
@@ -311,6 +312,18 @@ class FsRepository:
                     )
                     if engine.data_path is not None:
                         engine.flush()
+
+                shards = list(zip(svc.engines, meta["shards"]))
+                if len(shards) == 1:
+                    restore_shard(*shards[0])
+                else:
+                    # Shards restore side by side: each engine reads its
+                    # own blobs and packs onto its own device under its
+                    # own lock (the breaker and ledger lock their
+                    # shared counts). The first failure re-raises once
+                    # every shard has finished.
+                    with ThreadPoolExecutor(len(shards)) as pool:
+                        list(pool.map(lambda a: restore_shard(*a), shards))
                 restored.append(target)
         return {
             "snapshot": {

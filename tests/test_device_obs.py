@@ -278,7 +278,12 @@ class TestLedgerConsistency:
         node.create_index("gauges", _mappings())
         _index_docs(node, "gauges", 30)
         text = node.metrics_text()
-        assert 'estpu_hbm_bytes{index="gauges",label="segment"}' in text
+        # One series per device holding the bytes: a one-shard index
+        # packs on the default device.
+        assert (
+            'estpu_hbm_bytes{device="cpu:0",index="gauges",label="segment"}'
+            in text
+        )
         assert "estpu_hbm_high_watermark_bytes" in text
 
     def test_breaker_writes_through_any_ledger(self):

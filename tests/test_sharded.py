@@ -128,3 +128,27 @@ def test_routing_is_stable(sharded):
     for doc_id, _ in docs[:20]:
         s = shard_for_id(doc_id, index.n_shards)
         assert doc_id in index.segments[s].ids
+
+
+def test_pack_plan_round_trips_every_leaf_bit_for_bit():
+    """A stacked plan travels as one int32 buffer a shard; inside the
+    program each leaf comes back with its dtype, shape and bits."""
+    from elasticsearch_tpu.parallel.sharded import _unpack_plan, pack_plan
+
+    rng = np.random.default_rng(3)
+    arrays = {
+        "tile_ids": rng.integers(0, 1 << 20, (4, 64)).astype(np.int32),
+        "weights": rng.standard_normal((4, 64)).astype(np.float32),
+        "n_starts": rng.integers(0, 9, (4, 2)).astype(np.int32),
+        "ub_n": np.array([0.5, np.nan, -0.0, 1e30], dtype=np.float32),
+        "total_floor": np.arange(4, dtype=np.int32),
+    }
+    buf, layout = pack_plan(arrays)
+    assert buf.shape == (4, 64 + 64 + 2 + 1 + 1) and buf.dtype == np.int32
+    for s in range(4):
+        got = jax.jit(lambda row: _unpack_plan(row, layout))(buf[s])
+        for key, want in arrays.items():
+            assert got[key].dtype == want.dtype and got[key].shape == want.shape[1:]
+            assert np.asarray(got[key]).tobytes() == want[s].tobytes(), key
+    with pytest.raises(TypeError):
+        pack_plan({"mask": np.zeros((4, 8), dtype=bool)})

@@ -69,6 +69,41 @@ def test_snapshot_restore_roundtrip(tmp_path):
     assert resp["_seq_no"] > b["_seq_no"]
 
 
+def test_shards_restore_side_by_side_with_every_count_kept(tmp_path):
+    """An index's shards restore in threads: under a short switch
+    interval, every shard's docs, the breaker and the HBM ledger still
+    agree with a one-at-a-time build."""
+    import sys
+
+    node = Node()
+    seed(node, "wide", n=400, n_shards=8)
+    node.put_repository(
+        "repo", {"type": "fs", "settings": {"location": str(tmp_path / "r")}}
+    )
+    node.create_snapshot("repo", "s", {})
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        node.restore_snapshot(
+            "repo", "s",
+            {"rename_pattern": "wide", "rename_replacement": "copy"},
+        )
+    finally:
+        sys.setswitchinterval(interval)
+    src, copy = node.get_index("wide"), node.get_index("copy")
+    assert [e.num_docs for e in copy.engines] == [e.num_docs for e in src.engines]
+    assert sum(e.num_docs for e in copy.engines) == 400
+    hbm = node.nodes_stats()["nodes"][node.node_name]["device"]["hbm"]
+    assert hbm["breaker_drift_bytes"] == 0
+    assert node.hbm_ledger.bytes_for("segment") == sum(
+        e.device_bytes for e in src.engines + copy.engines
+    )
+    body = {"query": {"match": {"t": "w1"}}, "size": 200}
+    assert sorted(h["_id"] for h in node.search("copy", body)["hits"]["hits"]) == sorted(
+        h["_id"] for h in node.search("wide", body)["hits"]["hits"]
+    )
+
+
 def test_restore_collision_and_missing(tmp_path):
     node = Node()
     seed(node, "a", n=5)

@@ -21,7 +21,13 @@ from jax.sharding import SingleDeviceSharding
 from elasticsearch_tpu.index.mapping import Mappings
 from elasticsearch_tpu.index.tiles import pack_segment
 from elasticsearch_tpu.ops import bm25_device
-from elasticsearch_tpu.parallel.sharded import ShardedIndex, sharded_execute
+from elasticsearch_tpu.parallel.sharded import (
+    ShardedIndex,
+    sharded_execute,
+    pack_plan,
+    sharded_execute_maxscore,
+    stack_maxscore,
+)
 from elasticsearch_tpu.query.compile import Compiler
 from elasticsearch_tpu.query.dsl import parse_query
 from elasticsearch_tpu.utils.corpus import (
@@ -185,6 +191,47 @@ def test_mesh_four_shards(topo):
     )
     # Per-device arguments: one shard's planes, not the whole stack.
     assert mem.argument_size_in_bytes < stacked / 2
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    text = out.as_text()
+    assert "all-reduce" in text or "all-gather" in text
+
+
+def test_mesh_maxscore_four_shards(topo):
+    """The 4-shard SPMD MaxScore program MeshView serves stop-word
+    disjunctions with: each shard's own MaxScore plan, stacked and packed
+    into one buffer, laid out over the 2x2 chips; per-device memory fits,
+    and the merge and the check's `pmax` run as collectives."""
+    n_shards = 4
+    segments = [
+        build_zipf_segment(50_000, vocab_size=30_000, seed=20 + s)[1]
+        for s in range(n_shards)
+    ]
+    mappings = Mappings(properties={"body": {"type": "text"}})
+    cpu_mesh = Mesh(np.array(jax.devices()[:n_shards]), ("shard",))
+    index = ShardedIndex.from_segments(segments, mappings, cpu_mesh)
+    fld = segments[0].fields["body"]
+    by_df = sorted(fld.terms, key=lambda t: -fld.df[fld.terms[t]])
+    query = parse_query({"match": {"body": " ".join(
+        [by_df[0], by_df[2000], by_df[9000]]
+    )}})
+    plans = []
+    for seg in segments:
+        dev = pack_segment(seg)
+        plans.append(
+            Compiler(dev.fields, dev.doc_values, mappings).compile_maxscore(
+                query, 1_000
+            )
+        )
+    compiled = stack_maxscore(plans)
+    assert compiled.spec[0] == "terms_maxscore"
+    buf, layout = pack_plan(compiled.arrays)
+    chip_mesh = Mesh(np.array(topo.devices[:n_shards]), ("shard",))
+    sharded = NamedSharding(chip_mesh, P("shard"))
+    out = sharded_execute_maxscore.lower(
+        chip_mesh, "shard", _shapes(index.seg_stacked, sharded),
+        _shapes(buf, sharded), compiled.spec, 10, index.docs_per_shard, layout,
+    ).compile()
+    mem = out.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     text = out.as_text()
     assert "all-reduce" in text or "all-gather" in text
